@@ -20,6 +20,8 @@ def main() -> None:
                    help="paper-scale step counts (T=100 everywhere)")
     args = p.parse_args()
 
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     from benchmarks import (figure1_order_k, figure2_taa, table1_scenarios,
                             figure4_window, figure5_traj_init,
                             figure6_safeguard, figure7_grid, roofline_table,

@@ -103,6 +103,7 @@ class ArchConfig:
     # --- diffusion (DiT & DiffusionWrapper) -----------------------------------
     is_diffusion: bool = False
     latent_dim: int = 0  # per-token continuous latent dim (DiT patch dim)
+    num_tokens: int = 0  # latent tokens per sample (DiT: patches per image)
     num_classes: int = 0  # class-conditional diffusion
 
     # ------------------------------------------------------------------------
@@ -180,7 +181,8 @@ class ArchConfig:
         if self.is_ssm:
             changes.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=16)
         if self.is_diffusion:
-            changes.update(latent_dim=16, num_classes=min(self.num_classes, 16))
+            changes.update(latent_dim=16, num_tokens=16,
+                           num_classes=min(self.num_classes, 16))
         if self.m_rope:
             changes.update(m_rope_sections=(4, 6, 6))
         return dataclasses.replace(self, **changes)

@@ -21,6 +21,7 @@ CONFIG = ArchConfig(
     act="gelu",
     is_diffusion=True,
     latent_dim=16,              # 2x2 patch of 4-channel latents
+    num_tokens=256,             # (32 / 2)^2 patches of the 32x32 latent
     num_classes=1000,
     tp_strategy="heads",
 )

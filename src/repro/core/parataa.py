@@ -143,6 +143,15 @@ def _build_static(coeffs: SolverCoeffs, cfg: ParaTAAConfig):
     return static
 
 
+def _rows(mat, x):
+    """``mat @ x`` over trajectory rows in full f32.  The TPU's default f32
+    matmul rounds its inputs to bf16, an error of ~|x|/256 per element that
+    dwarfs the stopping thresholds (tau * sqrt(g2) ~ 1e-3) and keeps the
+    solve from converging; the CPU computes f32 either way."""
+    return jnp.matmul(mat, x.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 def _iterate(state: SolverState, static, cfg: ParaTAAConfig,
              eps_fn) -> SolverState:
     """One Algorithm-1 iteration.  Returns the new state."""
@@ -169,8 +178,8 @@ def _iterate(state: SolverState, static, cfg: ParaTAAConfig,
 
     # --- update residual R = F^(k)(x, e) - x (rows 0..T-1) ------------------
     # lift_k/weps_k contract OVER rows (triangular system) — replicated.
-    F = static["lift_k"] @ x.astype(jnp.float32) \
-        + static["weps_k"] @ e.astype(jnp.float32) + state.noise_k
+    F = _rows(static["lift_k"], x) + _rows(static["weps_k"], e) \
+        + state.noise_k
     R = window_constrain(F - x[:T].astype(jnp.float32), ta, replicate=True)
 
     # --- lines 4-9: first-order residuals, window bookkeeping ---------------
@@ -284,7 +293,7 @@ def init_state(coeffs: SolverCoeffs, cfg: ParaTAAConfig, xi,
     x0_f = None if x_init is None else x_init.reshape(T + 1, D)
 
     static = _build_static(coeffs, cfg)
-    noise_k = static["wxi_k"] @ xi_f.astype(jnp.float32)
+    noise_k = _rows(static["wxi_k"], xi_f)
     if tau_sq is None:
         tau_sq = cfg.tau ** 2
     thresh = tau_sq * static["thresh_scale"] * D

@@ -12,6 +12,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref as _ref
 from repro.kernels.flash_attention import flash_attention as _flash_attention
@@ -75,6 +76,24 @@ def _row_pin(x, time_axis, dim=0, *, replicate=False):
     return window_constrain(x, time_axis, dim, replicate=replicate)
 
 
+def _per_device(kernel, *args):
+    """Run a Pallas kernel call on every device of the ambient serving mesh.
+
+    GSPMD cannot partition a Mosaic kernel, so under a mesh the call goes
+    through ``shard_map`` with replicated specs: each device runs it on its
+    own copy of the operands.  Under the engine's
+    ``vmap(spmd_axis_name=data)`` the request axis becomes a ``data``-sharded
+    dim of that shard_map, so each device runs its own requests; on every
+    other mesh axis the per-request operands are whole (the ops below keep
+    them replicated anyway).  No mesh: a plain call."""
+    from repro.models.shardctx import current_mesh
+    mesh = current_mesh()
+    if mesh is None:
+        return kernel(*args)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(P(),) * len(args),
+                         out_specs=P(), check_vma=False)(*args)
+
+
 # Time-sharded dispatch notes (both caught by the bitwise suite):
 #
 #  * When ``time_axis`` is set the public wrappers run the implementation
@@ -96,7 +115,8 @@ def _row_pin(x, time_axis, dim=0, *, replicate=False):
 
 def _taa_gram_impl(dF, R, mask, use_pallas, interpret, time_axis):
     if _pick(use_pallas):
-        G, u = _taa_gram(dF, R, mask, interpret=interpret)
+        G, u = _per_device(functools.partial(_taa_gram, interpret=interpret),
+                           dF, R, mask)
     else:
         G, u = _ref.taa_gram_ref(dF, R, mask)
     return (_row_pin(G, time_axis, replicate=True),
@@ -161,7 +181,8 @@ def taa_rowwise_gamma(dF, R, mask, *, lam: float = 1e-8,
 def _taa_apply_impl(x, R, dX, dF, gamma, mask, use_pallas, interpret,
                     time_axis):
     if _pick(use_pallas):
-        out = _taa_apply(x, R, dX, dF, gamma, mask, interpret=interpret)
+        out = _per_device(functools.partial(_taa_apply, interpret=interpret),
+                          x, R, dX, dF, gamma, mask)
     else:
         out = _ref.taa_apply_ref(x, R, dX, dF, gamma, mask)
     return _row_pin(out, time_axis, replicate=True)
@@ -190,8 +211,10 @@ def _taa_round_impl(x, R, dX, dF, mask, guard, mode, lam, use_pallas,
     if _pick(use_pallas):
         g = jnp.zeros_like(mask) if guard is None \
             else guard.astype(jnp.float32)
-        out = _taa_round_kernel(x, R, dX, dF, mask, g, mode=mode, lam=lam,
-                                interpret=interpret)
+        out = _per_device(
+            functools.partial(_taa_round_kernel, mode=mode, lam=lam,
+                              interpret=interpret),
+            x, R, dX, dF, mask, g)
         return _row_pin(out, time_axis, replicate=True)
     # Staged reference: the EXACT primitives anderson_update's unfused path
     # composes, in the same order — gram, (suffix) reduce + solve, apply —

@@ -6,21 +6,29 @@ The suffix-cumsum reformulation (see repro.core.anderson) reduces TAA to:
   3. the update x_t + R_t - (dX_t + dF_t)^T gamma_t
 
 Steps 1 and 3 are memory-bound passes over the (m, T, D) histories;
-``taa_gram`` / ``taa_apply`` fuse each into a single HBM sweep.  Grid:
-(T, d_blocks) with the d-axis sequential so the (m, m)/(m,) partials
-accumulate in VMEM scratch.  m is padded to 8 (sublane) — the Gram tile
-stays in registers.
+``taa_gram`` / ``taa_apply`` fuse each into a single HBM sweep.  Every block
+spans ALL T rows and a 128-multiple tile of D, so the last two block dims
+are (T, bd): T equals the array's row count (legal for any T) and bd is
+lane-aligned.  The grid runs over D tiles only, sequentially, so the
+per-row partials accumulate in place.
+
+Per-row (m, m) / (m,) quantities never take their natural shape inside a
+kernel.  They live COLUMN-PACKED in one lane-dense (T, 128) f32 tile: lane
+``i*m + j`` holds G[:, i, j] and lane ``m*m + i`` holds u[:, i]
+(so m <= 10).  Each column is a lane reduction of an elementwise product
+over the D tile, so the kernels use only 2-D elementwise ops, lane
+reductions and lane-iota selects: no 1-D values, no in-kernel reshapes or
+concatenates, no SMEM scalars, and no matvecs for the MXU.
 
 ``taa_round`` goes further: ONE ``pallas_call`` for the whole round.  The
-grid grows a leading phase axis (2, T, d_blocks) — phase 0 is the Gram
-sweep with every (m, m)/(m,) row block parked in a (T, m, m)/(T, m) VMEM
-scratch instead of HBM; at the first step of phase 1 the suffix cumsum
-(an upper-triangular-ones matmul over the row axis), the ridge, and the T
-tiny (m, m) solves (unrolled pivot-free Gauss-Jordan — the Grams are
-SPD + ridge) all run in-register on those resident blocks; the rest of
-phase 1 is the apply sweep reading the (T, m) gammas straight from
-scratch.  Launches per round: 3 (gram + host solve + apply) -> 1, and the
-G/u/gamma intermediates never touch HBM or the host.
+grid grows a leading phase axis (2, d_blocks).  Phase 0 is the Gram sweep
+into a (T, 128) VMEM accumulator.  At the first step of phase 1 the suffix
+cumsum (a row loop over the accumulator, in place), the ridge and the T
+tiny (m, m) solves (unrolled pivot-free Gauss-Jordan on (T, 1) columns;
+the Grams are SPD + ridge) run on the resident tile.  The rest of phase 1
+is the apply sweep, which reads the gammas from VMEM.  Launches per round
+go from 3 (gram + host solve + apply) to 1, and the G/u/gamma intermediates
+never touch HBM or the host.
 """
 from __future__ import annotations
 
@@ -31,72 +39,107 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_LANES = 128
+# VMEM for the double-buffered (T, bd) blocks of one grid step: the D tile
+# shrinks for long schedules so x, R, out and the two histories fit.
+_VMEM_BLOCK_BUDGET = 8 << 20
 
-def _gram_kernel(df_ref, r_ref, mask_ref, g_ref, u_ref, acc_g, acc_u, *,
-                 m: int, bd: int):
-    di = pl.program_id(1)
-    nd = pl.num_programs(1)
 
-    @pl.when(di == 0)
+def _block_d(t: int, d: int, m: int, bd: int):
+    """(D tile, padded D): the tile is a multiple of 128 lanes, at most
+    ``bd``, within the VMEM budget, and divides the 128-padded D, so D is
+    padded only when it is not itself a multiple of 128."""
+    if m * m + m > _LANES:
+        raise ValueError(f"history m={m} does not fit the column-packed "
+                         f"(T, {_LANES}) Gram tile (m <= 10)")
+    dpad = -(-d // _LANES) * _LANES
+    fit = _VMEM_BLOCK_BUDGET // (2 * (3 + 2 * m) * t * 4)
+    tile = max(_LANES, min(bd, fit, dpad) // _LANES * _LANES)
+    while dpad % tile:
+        tile -= _LANES
+    return tile, dpad
+
+
+def _pad_d(a, dpad):
+    pad = dpad - a.shape[-1]
+    if not pad:
+        return a
+    return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
+
+
+def _lane(t: int):
+    return jax.lax.broadcasted_iota(jnp.int32, (t, _LANES), 1)
+
+
+def _gram_cols(df_ref, r_ref, w, *, m: int, t: int):
+    """This D tile's column-packed partial Grams (T, 128) for masked rows."""
+    f32 = jnp.float32
+    lane = _lane(t)
+    r = r_ref[...].astype(f32) * w
+    dfs = [df_ref[i].astype(f32) * w for i in range(m)]      # m x (T, bd)
+    acc = jnp.zeros((t, _LANES), f32)
+    for i in range(m):
+        for j in range(i, m):
+            col = jnp.sum(dfs[i] * dfs[j], axis=1, keepdims=True)
+            acc = jnp.where((lane == i * m + j) | (lane == j * m + i), col,
+                            acc)
+        col = jnp.sum(dfs[i] * r, axis=1, keepdims=True)
+        acc = jnp.where(lane == m * m + i, col, acc)
+    return acc
+
+
+def _col(tile, c: int, lane):
+    """Lane ``c`` of a (T, 128) tile as a (T, 1) column."""
+    return jnp.sum(jnp.where(lane == c, tile, 0.0), axis=1, keepdims=True)
+
+
+def _apply_rows(x_ref, r_ref, dx_ref, df_ref, gam, w, *, m: int, t: int):
+    """x + R - (dX + dF)^T gamma on rows with w > 0, x elsewhere (f32);
+    ``gam`` is the (T, 128) tile with gamma_i in lane i."""
+    f32 = jnp.float32
+    lane = _lane(t)
+    x = x_ref[...].astype(f32)
+    r = r_ref[...].astype(f32)
+    corr = jnp.zeros_like(x)
+    for i in range(m):
+        hist = dx_ref[i].astype(f32) + df_ref[i].astype(f32)
+        corr = corr + _col(gam, i, lane) * hist
+    return jnp.where(w > 0, x + r - corr, x)
+
+
+def _gram_kernel(df_ref, r_ref, mask_ref, gu_ref, *, m: int, t: int):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        acc_g[...] = jnp.zeros_like(acc_g)
-        acc_u[...] = jnp.zeros_like(acc_u)
+        gu_ref[...] = jnp.zeros_like(gu_ref)
 
-    w = mask_ref[0]
-    df = df_ref[:, 0].astype(jnp.float32) * w  # (m, bd)
-    r = r_ref[0].astype(jnp.float32) * w       # (bd,)
-    acc_g[...] += jax.lax.dot_general(df, df, (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-    acc_u[...] += (df @ r)[:, None]
-
-    @pl.when(di == nd - 1)
-    def _final():
-        g_ref[0] = acc_g[...]
-        u_ref[0] = acc_u[...][:, 0]
+    gu_ref[...] += _gram_cols(df_ref, r_ref, mask_ref[...], m=m, t=t)
 
 
 def taa_gram(dF, R, mask, *, bd: int = 512, interpret: bool = False):
     """dF: (m, T, D); R: (T, D); mask: (T,) f32 -> (G (T,m,m), u (T,m))."""
     m, t, d = dF.shape
-    pad = (-d) % bd
-    if pad:
-        dF = jnp.pad(dF, ((0, 0), (0, 0), (0, pad)))
-        R = jnp.pad(R, ((0, 0), (0, pad)))
-    dpad = d + pad
-    grid = (t, dpad // bd)
-    kernel = functools.partial(_gram_kernel, m=m, bd=bd)
-    g, u = pl.pallas_call(
-        kernel,
-        grid=grid,
+    bd, dpad = _block_d(t, d, m, bd)
+    dF, R = _pad_d(dF, dpad), _pad_d(R, dpad)
+    gu = pl.pallas_call(
+        functools.partial(_gram_kernel, m=m, t=t),
+        grid=(dpad // bd,),
         in_specs=[
-            pl.BlockSpec((m, 1, bd), lambda ti, di: (0, ti, di)),
-            pl.BlockSpec((1, bd), lambda ti, di: (ti, di)),
-            pl.BlockSpec((1,), lambda ti, di: (ti,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((m, t, bd), lambda di: (0, 0, di)),
+            pl.BlockSpec((t, bd), lambda di: (0, di)),
+            pl.BlockSpec((t, 1), lambda di: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, m, m), lambda ti, di: (ti, 0, 0)),
-            pl.BlockSpec((1, m), lambda ti, di: (ti, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((t, m, m), jnp.float32),
-            jax.ShapeDtypeStruct((t, m), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((m, m), jnp.float32),
-                        pltpu.VMEM((m, 1), jnp.float32)],
+        out_specs=pl.BlockSpec((t, _LANES), lambda di: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((t, _LANES), jnp.float32),
         interpret=interpret,
-    )(dF, R, mask)
-    return g, u
+    )(dF, R, mask.astype(jnp.float32).reshape(t, 1))
+    return gu[:, :m * m].reshape(t, m, m), gu[:, m * m:m * m + m]
 
 
 def _apply_kernel(x_ref, r_ref, dx_ref, df_ref, gam_ref, mask_ref, o_ref, *,
-                  m: int, bd: int):
-    w = mask_ref[0]
-    x = x_ref[0].astype(jnp.float32)           # (bd,)
-    r = r_ref[0].astype(jnp.float32)
-    hist = dx_ref[:, 0].astype(jnp.float32) + df_ref[:, 0].astype(jnp.float32)  # (m, bd)
-    gam = gam_ref[0].astype(jnp.float32)       # (m,)
-    corr = gam @ hist                          # (bd,)
-    o_ref[0] = jnp.where(w > 0, x + r - corr, x).astype(o_ref.dtype)
+                  m: int, t: int):
+    out = _apply_rows(x_ref, r_ref, dx_ref, df_ref, gam_ref[...],
+                      mask_ref[...], m=m, t=t)
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 def taa_apply(x, R, dX, dF, gamma, mask, *, bd: int = 512,
@@ -104,112 +147,96 @@ def taa_apply(x, R, dX, dF, gamma, mask, *, bd: int = 512,
     """x, R: (T, D); dX, dF: (m, T, D); gamma: (T, m); mask: (T,) f32 ->
     x + mask * (R - (dX + dF)^T gamma)."""
     m, t, d = dX.shape
-    pad = (-d) % bd
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-        R = jnp.pad(R, ((0, 0), (0, pad)))
-        dX = jnp.pad(dX, ((0, 0), (0, 0), (0, pad)))
-        dF = jnp.pad(dF, ((0, 0), (0, 0), (0, pad)))
-    dpad = d + pad
-    grid = (t, dpad // bd)
-    kernel = functools.partial(_apply_kernel, m=m, bd=bd)
+    bd, dpad = _block_d(t, d, m, bd)
+    x, R, dX, dF = (_pad_d(a, dpad) for a in (x, R, dX, dF))
+    gam = jnp.pad(gamma.astype(jnp.float32), ((0, 0), (0, _LANES - m)))
+    row = pl.BlockSpec((t, bd), lambda di: (0, di))
+    hist = pl.BlockSpec((m, t, bd), lambda di: (0, 0, di))
     out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bd), lambda ti, di: (ti, di)),
-            pl.BlockSpec((1, bd), lambda ti, di: (ti, di)),
-            pl.BlockSpec((m, 1, bd), lambda ti, di: (0, ti, di)),
-            pl.BlockSpec((m, 1, bd), lambda ti, di: (0, ti, di)),
-            pl.BlockSpec((1, m), lambda ti, di: (ti, 0)),
-            pl.BlockSpec((1,), lambda ti, di: (ti,), memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, bd), lambda ti, di: (ti, di)),
+        functools.partial(_apply_kernel, m=m, t=t),
+        grid=(dpad // bd,),
+        in_specs=[row, row, hist, hist,
+                  pl.BlockSpec((t, _LANES), lambda di: (0, 0)),
+                  pl.BlockSpec((t, 1), lambda di: (0, 0))],
+        out_specs=row,
         out_shape=jax.ShapeDtypeStruct((t, dpad), x.dtype),
         interpret=interpret,
-    )(x, R, dX, dF, gamma, mask)
+    )(x, R, dX, dF, gam, mask.astype(jnp.float32).reshape(t, 1))
     return out[:, :d]
 
 
 def _gauss_jordan(A, b, *, m: int):
-    """Batched pivot-free Gauss-Jordan solve A x = b; A: (n, m, m) SPD+ridge,
-    b: (n, m) -> (n, m).  m is static, so the elimination unrolls fully —
-    no gathers, no data-dependent control flow, VPU-only."""
-    aug = jnp.concatenate([A, b[..., None]], axis=-1)      # (n, m, m+1)
-    rowk = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)  # 2D iota (TPU)
+    """Pivot-free Gauss-Jordan solve of A x = b, one system per row: A is
+    an m x m list of (T, 1) columns (SPD + ridge), b a list of m columns.
+    m is static, so the elimination unrolls fully: VPU-only, no gathers,
+    no data-dependent control flow."""
+    A = [list(r) for r in A]
+    b = list(b)
     for k in range(m):
-        piv = aug[:, k, :] / aug[:, k, k:k + 1]            # (n, m+1)
-        factor = aug[:, :, k]                              # (n, m)
-        elim = aug - factor[..., None] * piv[:, None, :]
-        # row k eliminated itself to zero above: restore the normalized row
-        aug = jnp.where((rowk == k)[None], piv[:, None, :], elim)
-    return aug[:, :, m]
+        piv = A[k][k]
+        A[k] = [a / piv for a in A[k]]
+        b[k] = b[k] / piv
+        for i in range(m):
+            if i != k:
+                f = A[i][k]
+                A[i] = [A[i][j] - f * A[k][j] for j in range(m)]
+                b[i] = b[i] - f * b[k]
+    return b
+
+
+def _suffix_rows(ref, t: int):
+    """In-place reverse cumulative sum over the rows of a (T, 128) ref."""
+    def body(k, carry):
+        row = t - 2 - k
+        cur = ref[pl.ds(row, 1), :] + carry
+        ref[pl.ds(row, 1), :] = cur
+        return cur
+
+    jax.lax.fori_loop(0, t - 1, body, ref[pl.ds(t - 1, 1), :])
 
 
 def _round_kernel(x_ref, r_ref, dx_ref, df_ref, mask_ref, guard_ref, o_ref,
-                  g_all, u_all, gam, acc_g, acc_u, *,
-                  mode: str, lam: float, m: int, t: int):
+                  acc, gam, *, mode: str, lam: float, m: int, t: int):
     ph = pl.program_id(0)
-    ti = pl.program_id(1)
-    di = pl.program_id(2)
-    nd = pl.num_programs(2)
-    w = mask_ref[0]
+    di = pl.program_id(1)
+    w = mask_ref[...]
+
+    @pl.when((ph == 0) & (di == 0))
+    def _init():
+        acc[...] = jnp.zeros_like(acc)
 
     @pl.when(ph == 0)
     def _gram_sweep():
-        @pl.when(di == 0)
-        def _init():
-            acc_g[...] = jnp.zeros_like(acc_g)
-            acc_u[...] = jnp.zeros_like(acc_u)
+        acc[...] += _gram_cols(df_ref, r_ref, w, m=m, t=t)
 
-        df = df_ref[:, 0].astype(jnp.float32) * w  # (m, bd)
-        r = r_ref[0].astype(jnp.float32) * w       # (bd,)
-        acc_g[...] += jax.lax.dot_general(df, df, (((1,), (1,)), ((), ())),
-                                          preferred_element_type=jnp.float32)
-        acc_u[...] += (df @ r)[:, None]
-
-        @pl.when(di == nd - 1)
-        def _park():
-            g_all[pl.ds(ti, 1)] = acc_g[...][None]
-            u_all[pl.ds(ti, 1)] = acc_u[...][:, 0][None]
+    @pl.when((ph == 1) & (di == 0))
+    def _solve():
+        lane = _lane(t)
+        _suffix_rows(acc, t)                     # row s = sum over rows >= s
+        suf = acc[...]
+        total = jnp.broadcast_to(acc[pl.ds(0, 1), :], (t, _LANES))
+        if mode == "taa":
+            red = suf
+        elif mode == "aa":
+            red = total
+        elif mode == "aa+":                      # global Gram, suffix u
+            red = jnp.where(lane < m * m, total, suf)
+        else:
+            raise ValueError(mode)
+        A = [[_col(red, i * m + j, lane) + (lam if i == j else 0.0)
+              for j in range(m)] for i in range(m)]
+        b = [_col(red, m * m + i, lane) for i in range(m)]
+        gamma = _gauss_jordan(A, b, m=m)
+        keep = guard_ref[...] <= 0
+        g = jnp.zeros((t, _LANES), jnp.float32)
+        for i in range(m):
+            g = jnp.where(lane == i, jnp.where(keep, gamma[i], 0.0), g)
+        gam[...] = g
 
     @pl.when(ph == 1)
-    def _solve_and_apply():
-        @pl.when((ti == 0) & (di == 0))
-        def _solve():
-            G = g_all[...]                                  # (t, m, m)
-            u = u_all[...]                                  # (t, m)
-            row = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
-            col = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
-            upper = (col >= row).astype(jnp.float32)        # suffix-sum op
-            ei = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)
-            ej = jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)
-            eye = (ei == ej).astype(jnp.float32)
-            if mode == "taa":
-                Gs = (upper @ G.reshape(t, m * m)).reshape(t, m, m) \
-                    + lam * eye
-                us = upper @ u
-            elif mode == "aa":
-                Gs = jnp.broadcast_to((jnp.sum(G, 0) + lam * eye)[None],
-                                      (t, m, m))
-                us = jnp.broadcast_to(jnp.sum(u, 0)[None], (t, m))
-            elif mode == "aa+":
-                Gs = jnp.broadcast_to((jnp.sum(G, 0) + lam * eye)[None],
-                                      (t, m, m))
-                us = upper @ u
-            else:
-                raise ValueError(mode)
-            gamma = _gauss_jordan(Gs, us, m=m)              # (t, m)
-            guard = guard_ref[0]                            # (t,)
-            gam[...] = jnp.where(guard[:, None] > 0, 0.0, gamma)
-
-        x = x_ref[0].astype(jnp.float32)           # (bd,)
-        r = r_ref[0].astype(jnp.float32)
-        hist = dx_ref[:, 0].astype(jnp.float32) \
-            + df_ref[:, 0].astype(jnp.float32)     # (m, bd)
-        gv = gam[pl.ds(ti, 1)][0]                  # (m,)
-        corr = gv @ hist                           # (bd,)
-        o_ref[0] = jnp.where(w > 0, x + r - corr, x).astype(o_ref.dtype)
+    def _apply():
+        out = _apply_rows(x_ref, r_ref, dx_ref, df_ref, gam[...], w, m=m, t=t)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 def taa_round(x, R, dX, dF, mask, guard, *, mode: str = "taa",
@@ -221,41 +248,30 @@ def taa_round(x, R, dX, dF, mask, guard, *, mode: str = "taa",
     guard: (T,) f32 — rows > 0 get gamma forced to 0 (Theorem 3.6
     safeguard; pass zeros for no safeguard).  Returns (T, D) in x.dtype.
 
-    Grid (2, T, d_blocks): the out/x/dX index maps multiply by the phase
-    id, pinning their block at (0, 0) through the whole Gram sweep — the
-    output block is only flushed after phase 1's first step has written
-    it, so nothing undefined reaches HBM.
+    Grid (2, d_blocks): the out/x/dX index maps multiply by the phase id,
+    pinning their block at 0 through the whole Gram sweep — the output
+    block is only flushed after phase 1's first step has written it, so
+    nothing undefined reaches HBM.
     """
     m, t, d = dF.shape
-    pad = (-d) % bd
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-        R = jnp.pad(R, ((0, 0), (0, pad)))
-        dX = jnp.pad(dX, ((0, 0), (0, 0), (0, pad)))
-        dF = jnp.pad(dF, ((0, 0), (0, 0), (0, pad)))
-    dpad = d + pad
-    grid = (2, t, dpad // bd)
-    kernel = functools.partial(_round_kernel, mode=mode, lam=lam, m=m, t=t)
+    bd, dpad = _block_d(t, d, m, bd)
+    x, R, dX, dF = (_pad_d(a, dpad) for a in (x, R, dX, dF))
+    col = pl.BlockSpec((t, 1), lambda ph, di: (0, 0))
     out = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_round_kernel, mode=mode, lam=lam, m=m, t=t),
+        grid=(2, dpad // bd),
         in_specs=[
-            pl.BlockSpec((1, bd), lambda ph, ti, di: (ti * ph, di * ph)),
-            pl.BlockSpec((1, bd), lambda ph, ti, di: (ti, di)),
-            pl.BlockSpec((m, 1, bd),
-                         lambda ph, ti, di: (0, ti * ph, di * ph)),
-            pl.BlockSpec((m, 1, bd), lambda ph, ti, di: (0, ti, di)),
-            pl.BlockSpec((1,), lambda ph, ti, di: (ti,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, t), lambda ph, ti, di: (0, 0)),
+            pl.BlockSpec((t, bd), lambda ph, di: (0, di * ph)),
+            pl.BlockSpec((t, bd), lambda ph, di: (0, di)),
+            pl.BlockSpec((m, t, bd), lambda ph, di: (0, 0, di * ph)),
+            pl.BlockSpec((m, t, bd), lambda ph, di: (0, 0, di)),
+            col, col,
         ],
-        out_specs=pl.BlockSpec((1, bd), lambda ph, ti, di: (ti * ph, di * ph)),
+        out_specs=pl.BlockSpec((t, bd), lambda ph, di: (0, di * ph)),
         out_shape=jax.ShapeDtypeStruct((t, dpad), x.dtype),
-        scratch_shapes=[pltpu.VMEM((t, m, m), jnp.float32),
-                        pltpu.VMEM((t, m), jnp.float32),
-                        pltpu.VMEM((t, m), jnp.float32),
-                        pltpu.VMEM((m, m), jnp.float32),
-                        pltpu.VMEM((m, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((t, _LANES), jnp.float32),
+                        pltpu.VMEM((t, _LANES), jnp.float32)],
         interpret=interpret,
-    )(x, R, dX, dF, mask, guard.reshape(1, t))
+    )(x, R, dX, dF, mask.astype(jnp.float32).reshape(t, 1),
+      guard.astype(jnp.float32).reshape(t, 1))
     return out[:, :d]

@@ -22,7 +22,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,7 +88,10 @@ class MeshSpec:
                 f"{'/--time-parallel' if 'time' in self.axes else ''}, or "
                 f"force host devices with XLA_FLAGS=--xla_force_host_"
                 f"platform_device_count={n}")
-        return jax.make_mesh(self.shape, self.axes)
+        # Auto axes: the serving stack shards through with_sharding_constraint
+        # and NamedSharding, which only resolve against Auto mesh axes
+        return jax.make_mesh(self.shape, self.axes,
+                             axis_types=(AxisType.Auto,) * len(self.axes))
 
 
 _REGISTRY: Dict[str, MeshSpec] = {}

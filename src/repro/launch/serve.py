@@ -114,6 +114,7 @@ import numpy as np
 from repro.configs.registry import get_arch
 from repro.core import ddim_coeffs, ddpm_coeffs
 from repro.diffusion import dit as dit_mod
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_mesh, mesh_names
 from repro.obs import Observability
 from repro.runtime import StragglerMitigator
@@ -143,10 +144,11 @@ def make_placement(mesh_name: str = "none", *, data_parallel: int = 0,
     return Placement.for_mesh(mesh, donate=donate)
 
 
-def make_engine(params, cfg, coeffs, spec, *, num_tokens=16,
-                placement: Placement = None):
+def make_engine(params, cfg, coeffs, spec, *, placement: Placement = None):
+    """One engine at the configuration's own latent shape: ``num_tokens``
+    tokens of ``latent_dim`` (256 x 16 for DiT-XL/2 at 256x256)."""
     return SamplingEngine(make_eps_apply(cfg), params, coeffs, spec,
-                          sample_shape=(num_tokens, cfg.latent_dim),
+                          sample_shape=(cfg.num_tokens, cfg.latent_dim),
                           placement=placement,
                           param_defs=dit_mod.dit_defs(cfg))
 
@@ -166,6 +168,14 @@ def serve_batch(engine: SamplingEngine, requests, *, batch_size=None):
     stats = [{"label": res.request.label, "iters": res.iters, "nfe": res.nfe,
               "wall_s": res.wall_s} for res in results]
     return jnp.stack([res.x0 for res in results]), stats, straggler
+
+
+def make_requests(args, cfg):
+    """``--requests`` class-conditional requests drawn from ``--seed``."""
+    rng = np.random.default_rng(args.seed)
+    return [SampleRequest(label=int(rng.integers(0, cfg.num_classes)),
+                          seed=int(rng.integers(1 << 30)))
+            for _ in range(args.requests)]
 
 
 def resolve_coeffs(args, T: int):
@@ -286,11 +296,18 @@ def serve_async(args, cfg, params, placement: Placement):
                            depth=args.async_depth,
                            chunk_iters=args.chunk_iters,
                            refiner=refiner, cache=args.cache, obs=obs)
+    def compiled(key):
+        stats = registry.get(key).stats
+        return stats["traces"] + stats["stepwise_traces"]
+
+    warm = {}
     for key in keys:  # compile ahead of traffic so p95 is not a jit compile
         engine = registry.get(key)
         registry.warmup(key, slots=loop.batcher.slots_for(engine),
                         chunk_iters=args.chunk_iters)
-        print(f"warmed {key.describe()}: {engine.placement.describe()}")
+        warm[key] = compiled(key)
+        print(f"warmed {key.describe()}: {engine.placement.describe()}, "
+              f"{warm[key]} program(s) compiled")
 
     rng = np.random.default_rng(args.seed)
     gaps = simulate_arrivals(rng, args.requests, args.arrival_rate)
@@ -312,6 +329,8 @@ def serve_async(args, cfg, params, placement: Placement):
     latencies = np.asarray([t.latency_s for t in tickets])
     span = max(t.completed_time for t in tickets) \
         - min(t.request.arrival_time for t in tickets)
+    # programs compiled after warm-up (0 unless traffic forced a retrace)
+    retraces = {key: compiled(key) - warm[key] for key in keys}
     stats = []
     for ticket, res in zip(tickets, results):
         stats.append({"key": ticket.key.describe(), "label": res.request.label,
@@ -319,7 +338,9 @@ def serve_async(args, cfg, params, placement: Placement):
                       "early_stopped": res.early_stopped,
                       "latency_s": ticket.latency_s,
                       "draft_latency_s": ticket.draft_latency_s,
-                      "refines": ticket.refines})
+                      "refines": ticket.refines,
+                      "warmup_programs": warm[ticket.key],
+                      "retraces": retraces[ticket.key]})
         early = " early-exit" if res.early_stopped else ""
         two_tier = (f" draft@{ticket.draft_latency_s:.2f}s"
                     if ticket.refines else "")
@@ -353,7 +374,8 @@ def serve_async(args, cfg, params, placement: Placement):
           f"latency p50 {np.percentile(latencies, 50):.2f}s "
           f"p95 {np.percentile(latencies, 95):.2f}s; "
           f"mean NFE/request {np.mean([r.nfe for r in results]):.0f}; "
-          f"{n_early} early-exit(s); loop stats {loop.stats}")
+          f"{n_early} early-exit(s); {sum(retraces.values())} program(s) "
+          f"compiled after warm-up; loop stats {loop.stats}")
     if args.chaos_drop:
         res = loop.resilience
         unresolved = [t for t in tickets if not t.done()]
@@ -411,7 +433,9 @@ def report_dispatches(engine: SamplingEngine, *, out=print):
             f"wall {d['wall_s']:.2f}s")
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI's argument parser (``main`` and the chip smoke
+    script build their ``args`` from it)."""
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="dit-xl")
     p.add_argument("--smoke", action="store_true")
@@ -522,19 +546,16 @@ def main(argv=None):
                         "convergence curves (see tools/obs_report.py)")
     p.add_argument("--ckpt", default=None, help="trained DiT checkpoint dir")
     p.add_argument("--seed", type=int, default=0)
-    args = p.parse_args(argv)
+    return p
 
-    placement = make_placement(args.mesh, data_parallel=args.data_parallel,
-                               model_parallel=args.model_parallel,
-                               time_parallel=args.time_parallel,
-                               donate=args.donate)
-    print(f"placement: {placement.describe()}")
 
+def make_params(args):
+    """(cfg, params) for ``--arch``/``--smoke``: weights drawn from
+    ``--seed``, or restored from ``--ckpt`` when given."""
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
-    key = jax.random.PRNGKey(args.seed)
-    params = dit_mod.dit_init(cfg, key)
+    params = dit_mod.dit_init(cfg, jax.random.PRNGKey(args.seed))
     if args.ckpt:
         from pathlib import Path
         from repro.ckpt import CheckpointManager
@@ -543,6 +564,18 @@ def main(argv=None):
         if tree is not None:
             params = tree["params"]
             print(f"restored checkpoint step {tree['step']}")
+    return cfg, params
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+
+    placement = make_placement(args.mesh, data_parallel=args.data_parallel,
+                               model_parallel=args.model_parallel,
+                               time_parallel=args.time_parallel,
+                               donate=args.donate)
+    print(f"placement: {placement.describe()}")
+    cfg, params = make_params(args)
 
     if args.serve_async:
         return serve_async(args, cfg, params, placement)
@@ -551,12 +584,8 @@ def main(argv=None):
     engine = make_engine(params, cfg, coeffs,
                          resolve_spec(args, args.solver), placement=placement)
 
-    rng = np.random.default_rng(args.seed)
-    requests = [SampleRequest(label=int(rng.integers(0, cfg.num_classes)),
-                              seed=int(rng.integers(1 << 30)))
-                for _ in range(args.requests)]
     outs, stats, straggler = serve_batch(
-        engine, requests, batch_size=args.batch_size or None)
+        engine, make_requests(args, cfg), batch_size=args.batch_size or None)
     for st in stats:
         # wall_s is the wall time of the DISPATCH the request rode in (its
         # latency), not exclusive per-request compute — batch members share it
@@ -576,4 +605,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

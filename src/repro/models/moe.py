@@ -142,7 +142,6 @@ def _moe_local(params, cfg: ArchConfig, x, capacity: int | None = None):
 
 
 def _moe_shard_map(params, cfg: ArchConfig, x, mesh, dp_axes, msize: int):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, s, d = x.shape
@@ -199,7 +198,7 @@ def _moe_shard_map(params, cfg: ArchConfig, x, mesh, dp_axes, msize: int):
         return out.astype(xl.dtype).reshape(bl, s, d), aux
 
     dp_spec = dp_axes if len(dp_axes) > 1 else dp_axes[0]
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(dp_spec, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),

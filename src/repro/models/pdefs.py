@@ -10,6 +10,7 @@ the axis size; otherwise it is replicated).
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Optional, Tuple
 
 import jax
@@ -98,7 +99,9 @@ def stack_defs(defs, n: int):
 
 
 def _leaf_key(key, path) -> jax.Array:
-    h = np.uint32(abs(hash(jax.tree_util.keystr(path))) % (2**31))
+    # crc32, not hash(): str hashes are salted per process, so the same seed
+    # would draw different weights in every run
+    h = zlib.crc32(jax.tree_util.keystr(path).encode()) % (2**31)
     return jax.random.fold_in(key, h)
 
 

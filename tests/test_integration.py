@@ -1,5 +1,9 @@
 """Integration tests: trained-DiT sampler equivalence (the paper's central
 claim end-to-end), the train/serve drivers, and checkpoint-restart."""
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,6 +85,46 @@ def test_serve_driver_smoke():
                         "--solver", "taa", "--batch-size", "2"])
     assert outs.shape[0] == 4
     assert all(s["iters"] < 20 for s in stats)
+
+
+@pytest.mark.parametrize("smoke", [False, True])
+def test_serve_engines_take_the_configured_latent_shape(smoke):
+    """Sync (`make_engine`) and async (the registry's factory) serving build
+    the config's own latent shape: 256 tokens x 16 for DiT-XL/2 at 256x256,
+    the reduced config's under --smoke."""
+    from repro.launch import serve
+    from repro.sampling import Placement
+    from repro.serving import EngineKey
+    argv = ["--steps-T", "4"] + (["--smoke"] if smoke else [])
+    args = serve.build_parser().parse_args(argv)
+    cfg = ARCHS["dit-xl"].reduced() if smoke else ARCHS["dit-xl"]
+    want = (16, 16) if smoke else (256, 16)
+    sync = serve.make_engine(None, cfg, ddim_coeffs(4), get_sampler("taa"))
+    factory = serve.make_engine_factory(cfg, None, args, Placement.host())
+    assert sync.sample_shape == want
+    assert factory(EngineKey(args.arch, 4, "taa")).sample_shape == want
+
+
+def test_seeded_weights_do_not_depend_on_the_process():
+    """`--seed` alone fixes the weights: each leaf's key is folded from a
+    stable digest of its path, not from Python's per-process salted hash."""
+    script = ("import jax, numpy as np\n"
+              "from repro.configs.registry import get_arch\n"
+              "from repro.diffusion import dit\n"
+              "p = dit.dit_init(get_arch('dit-xl').reduced(), "
+              "jax.random.PRNGKey(0))\n"
+              "print(repr(float(sum(np.abs(np.asarray(l, np.float64)).sum()"
+              " for l in jax.tree.leaves(p)))))\n")
+    outs = set()
+    for hash_seed in ("1", "2"):
+        proc = subprocess.run(
+            [sys.executable, "-c", script], capture_output=True, text=True,
+            env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                 "JAX_PLATFORMS": "cpu", "PYTHONHASHSEED": hash_seed},
+            cwd=Path(__file__).resolve().parent.parent, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        outs.add(proc.stdout.strip().splitlines()[-1])
+    assert len(outs) == 1, outs
 
 
 def test_serve_matches_sequential_solver():

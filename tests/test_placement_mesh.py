@@ -285,6 +285,72 @@ def test_sharded_engine_matches_unsharded():
     assert out["diag_equal"]
 
 
+# --- Pallas kernels under a serving mesh (subprocess, 8 host devices) --------
+
+KERNEL_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import json
+import jax, jax.numpy as jnp, numpy as np
+from repro.kernels import ops
+from repro.launch.mesh import make_mesh
+from repro.models.shardctx import serving_mesh
+
+B, m, T, D = 4, 3, 12, 256
+ks = jax.random.split(jax.random.PRNGKey(0), 4)
+x = jax.random.normal(ks[0], (B, T, D))
+R = 0.3 * jax.random.normal(ks[1], (B, T, D))
+dX = 0.1 * jax.random.normal(ks[2], (B, m, T, D))
+dF = 0.1 * jax.random.normal(ks[3], (B, m, T, D))
+mask = jnp.ones((B, T)).at[:, :2].set(0.0)
+guard = jnp.zeros((B, T), bool).at[:, -2:].set(True)
+gamma = 0.1 * jax.random.normal(ks[0], (B, T, m))
+kw = dict(use_pallas=True, interpret=True)
+
+def calls(x, R, dX, dF, mask, guard, gamma):
+    G, u = ops.taa_gram(dF, R, mask, **kw)
+    out = ops.taa_apply(x, R, dX, dF, gamma, mask, **kw)
+    fused = ops.taa_round(x, R, dX, dF, mask, mode="taa", lam=1e-6,
+                          safeguard_mask=guard, **kw)
+    return G, u, out, fused
+
+args = (x, R, dX, dF, mask, guard, gamma)
+ref = jax.jit(jax.vmap(calls))(*args)
+out = {}
+for name, mesh in [("data4_model2", make_mesh("debug", data_parallel=4,
+                                               model_parallel=2)),
+                   ("data2_time2_model2", make_mesh("debug-time"))]:
+    with serving_mesh(mesh):
+        got = jax.jit(jax.vmap(calls, spmd_axis_name="data"))(*args)
+    out[name] = {
+        "equal": all(np.array_equal(np.asarray(g), np.asarray(r))
+                     for g, r in zip(got, ref)),
+        "devices": len(got[3].sharding.device_set),
+        "spec": [str(a) for a in got[3].sharding.spec]}
+print("RESULT " + json.dumps(out))
+"""
+
+
+@pytest.mark.mesh
+def test_pallas_kernels_run_per_device_under_serving_mesh():
+    """GSPMD cannot partition a Mosaic kernel, so under a serving mesh the
+    ops run each kernel per device (shard_map): the request axis stays
+    sharded over `data` and the values equal the meshless calls."""
+    proc = subprocess.run(
+        [sys.executable, "-c", KERNEL_SCRIPT], capture_output=True,
+        text=True,
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu"},
+        cwd=Path(__file__).resolve().parent.parent, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")][0]
+    out = json.loads(line[7:])
+    for name, rec in out.items():
+        assert rec["equal"], name
+        assert rec["devices"] == 8, name
+        assert rec["spec"][0] == "data", name
+
+
 # --- dry-run parataa cell measures the engine's sharded program -------------
 
 DRYRUN_SCRIPT = r"""

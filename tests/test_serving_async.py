@@ -958,7 +958,7 @@ out = {"slots": sorted({d["slots"] for e in registry.engines().values()
                             for e in registry.engines().values()
                             for d in e.last_dispatches)}
 
-equal = True
+counts_equal, max_diff, max_abs = True, 0.0, 0.0
 for kk in (k1, k2):
     mine = [(t, i) for i, (t, k) in enumerate(zip(tickets, keys)) if k == kk]
     host = SamplingEngine(eps_apply, None, ddim_coeffs(kk.T),
@@ -966,10 +966,14 @@ for kk in (k1, k2):
     ref = host.run_batch([reqs[i] for _, i in mine], batch_size=4)
     for (t, _), r in zip(mine, ref):
         got = t.result()
-        equal = equal and np.array_equal(np.asarray(got.trajectory),
-                                         np.asarray(r.trajectory)) \
-            and got.iters == r.iters and got.nfe == r.nfe
-out["equal"] = bool(equal)
+        diff = np.asarray(got.trajectory) - np.asarray(r.trajectory)
+        max_diff = max(max_diff, float(np.max(np.abs(diff))))
+        max_abs = max(max_abs, float(np.max(np.abs(r.trajectory))))
+        counts_equal = counts_equal and got.iters == r.iters \
+            and got.nfe == r.nfe
+out["counts_equal"] = bool(counts_equal)
+out["max_diff"] = max_diff
+out["max_abs"] = max_abs
 out["loop"] = loop.stats
 print("RESULT " + json.dumps(out))
 """
@@ -985,7 +989,15 @@ def test_async_serving_sharded_matches_host_run_batch():
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")][0]
     out = json.loads(line[7:])
-    assert out["equal"], "async sharded serving diverged from host run_batch"
+    # The sharded program batches 1 request per data shard, the host one 4:
+    # XLA:CPU (jax 0.9) compiles the two batch geometries differently, so
+    # f32 rounding differs and compounds over the solve (observed max
+    # |diff| 3.1e-5 on trajectories of max magnitude 3.4, ~1e-5 relative).
+    # 1e-4 leaves 3x headroom and is 10x under the solver's stopping
+    # tolerance tau=1e-3.  Iteration and NFE counts stay exact.
+    assert out["max_diff"] <= 1e-4, \
+        f"async sharded serving diverged from host run_batch: {out}"
+    assert out["counts_equal"], out
     assert out["slots"] == [4]                 # 3 rounded up to 4 data shards
     assert out["devices"] == [8]
     assert out["traces"] == [1, 1]             # one compile per key
