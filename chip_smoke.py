@@ -41,6 +41,9 @@ import numpy as np  # noqa: E402
 
 from repro.launch import serve  # noqa: E402
 from repro.launch.compile_cache import configure_compile_cache  # noqa: E402
+from repro.obs import (MetricsRegistry, compile_totals,  # noqa: E402
+                       count_compiles)
+from repro.obs.compiles import COUNTERS  # noqa: E402
 from repro.sampling import WarmStart  # noqa: E402
 
 T_ONE_CHIP = 25
@@ -62,30 +65,12 @@ def check(ok: bool, what: str) -> None:
         raise SystemExit(f"chip_smoke: FAILED: {what}")
 
 
-class CompileMeter:
-    """Seconds JAX spends tracing, lowering and compiling (persistent-cache
-    reads included) and persistent-cache hits, read from jax.monitoring."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event.startswith("/jax/core/compile/"):
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def mark(self):
-        return self.seconds, self.cache_hits
-
-    def since(self, mark) -> str:
-        return (f"compile {self.seconds - mark[0]:.1f}s, "
-                f"{self.cache_hits - mark[1]} persistent-cache hit(s)")
+def since(metrics: MetricsRegistry, mark: dict) -> str:
+    """JAX's compile counters since ``mark`` (a ``compile_totals``)."""
+    now = compile_totals(metrics)
+    return (f"{now['jax.compiles'] - mark['jax.compiles']:.0f} compile(s), "
+            f"{now['jax.cache_hits'] - mark['jax.cache_hits']:.0f} "
+            f"persistent-cache hit(s)")
 
 
 def wake_adaln_zero(params, seed: int):
@@ -135,10 +120,10 @@ def parse(argv):
     return serve.build_parser().parse_args(argv)
 
 
-def sync_run(engine, requests, tag: str, meter: CompileMeter):
+def sync_run(engine, requests, tag: str, metrics: MetricsRegistry):
     """serve_batch once cold and once warm; returns x0."""
     T = engine.coeffs.T
-    mark = meter.mark()
+    mark = compile_totals(metrics)
     t0 = time.perf_counter()
     x0, stats, _ = serve.serve_batch(engine, requests,
                                      batch_size=len(requests))
@@ -151,7 +136,7 @@ def sync_run(engine, requests, tag: str, meter: CompileMeter):
     print(f"{tag}: {engine.spec.name} T={T} sample_shape="
           f"{engine.sample_shape} {engine.placement.describe()}; iters "
           f"{iters}, nfe {[s['nfe'] for s in stats]}; cold dispatch "
-          f"{cold:.1f}s ({meter.since(mark)}); warm dispatch {warm:.3f}s "
+          f"{cold:.1f}s ({since(metrics, mark)}); warm dispatch {warm:.3f}s "
           f"(smoke timing, not a benchmark)")
     check(np.all(np.isfinite(x0)), f"{tag}: non-finite x0")
     check(np.array_equal(np.asarray(again), x0),
@@ -171,7 +156,7 @@ def agree(tag: str, x0, ref, tol: float) -> None:
     check(max(diffs) <= tol, f"{tag}: |dx0| {diffs} exceeds {tol}")
 
 
-def one_chip(base, meter: CompileMeter):
+def one_chip(base, metrics: MetricsRegistry):
     # (a) model set-up
     args = parse(base + ["--requests", str(REQUESTS), "--batch-size",
                                 str(REQUESTS), "--steps-T", str(T_ONE_CHIP)])
@@ -190,19 +175,19 @@ def one_chip(base, meter: CompileMeter):
     engine = serve.make_engine(params, cfg, coeffs,
                                serve.resolve_spec(args, args.solver),
                                placement=placement)
-    x0_taa = sync_run(engine, requests, "(b) sync", meter)
-    mark = meter.mark()
+    x0_taa = sync_run(engine, requests, "(b) sync", metrics)
+    mark = compile_totals(metrics)
     text = engine.lower_batch(len(requests)).compile().as_text()
     kernels = text.count("tpu_custom_call")
     print(f"(b) lower_batch program: {kernels} tpu_custom_call site(s) "
-          f"({meter.since(mark)})")
+          f"({since(metrics, mark)})")
     check(kernels > 0, "(b) no Pallas kernel in the compiled program")
 
     # (c) sequential reference
     seq = serve.make_engine(params, cfg, coeffs,
                             serve.resolve_spec(args, "seq"),
                             placement=placement)
-    x0_seq = sync_run(seq, requests, "(c) seq", meter)
+    x0_seq = sync_run(seq, requests, "(c) seq", metrics)
     tol = stop_rule_scale(engine)
     agree("(c) taa vs seq", x0_taa, x0_seq, tol)
     # The sequential trajectories, handed to the taa engine as fully solved
@@ -226,7 +211,7 @@ def one_chip(base, meter: CompileMeter):
     fused = serve.make_engine(params, cfg, coeffs,
                               serve.resolve_spec(fargs, fargs.solver),
                               placement=placement)
-    x0_fused = sync_run(fused, requests, "(d) fused", meter)
+    x0_fused = sync_run(fused, requests, "(d) fused", metrics)
     agree("(d) fused vs seq", x0_fused, x0_seq, tol)
     # each is within tol of x0_seq, so within 2 * tol of the other
     agree("(d) fused vs staged", x0_fused, x0_taa, 2 * tol)
@@ -236,14 +221,14 @@ def one_chip(base, meter: CompileMeter):
         "--serve-async", "--chunk-iters", "2", "--requests", str(REQUESTS),
         "--batch-size", str(REQUESTS), "--mixed-keys", "1", "--steps-T",
         str(T_ONE_CHIP), "--arrival-rate", "0"])
-    mark = meter.mark()
+    mark = compile_totals(metrics)
     x0_async, stats = serve.serve_async(aargs, cfg, params, placement)
     x0_async = np.asarray(x0_async)
     iters = [s["iters"] for s in stats]
     print(f"(e) async: {len(stats)}/{REQUESTS} ticket(s) resolved, iters "
           f"{iters}; warm-up compiled {stats[0]['warmup_programs']} "
           f"program(s), {stats[0]['retraces']} after it "
-          f"({meter.since(mark)})")
+          f"({since(metrics, mark)})")
     check(len(stats) == REQUESTS, "(e) a ticket did not resolve")
     check(np.all(np.isfinite(x0_async)), "(e) non-finite x0")
     check(all(1 < it <= T_ONE_CHIP for it in iters),
@@ -252,7 +237,7 @@ def one_chip(base, meter: CompileMeter):
           "(e) serving retraced after warm-up")
 
 
-def four_chips(base, meter: CompileMeter):
+def four_chips(base, metrics: MetricsRegistry):
     common = ["--requests", str(REQUESTS), "--batch-size", str(REQUESTS),
               "--steps-T", str(T_FOUR_CHIPS)]
     args = parse(base + common)
@@ -275,7 +260,7 @@ def four_chips(base, meter: CompileMeter):
             model_parallel=a.model_parallel, time_parallel=a.time_parallel)
         engine = serve.make_engine(params, cfg, coeffs, spec,
                                    placement=placement)
-        x0 = sync_run(engine, requests, f"[{name}]", meter)
+        x0 = sync_run(engine, requests, f"[{name}]", metrics)
         pending = engine.dispatch(requests, slots=len(requests))
         on = {"params": {len(leaf.sharding.device_set)
                          for leaf in jax.tree.leaves(engine.params)},
@@ -313,10 +298,10 @@ def main(argv=None) -> None:
     check(len(devices) >= want, f"needs {want} chip(s), found {len(devices)}")
     print(f"device: {dev.device_kind} x {len(devices)}; compile cache "
           f"{cache}")
-    meter = CompileMeter()
+    metrics = count_compiles(MetricsRegistry())
     base = ["--arch", "dit-xl", "--seed", str(opts.seed)]
-    (four_chips if opts.four_chips else one_chip)(base, meter)
-    print(f"total {meter.since((0.0, 0))}")
+    (four_chips if opts.four_chips else one_chip)(base, metrics)
+    print(f"total {since(metrics, dict.fromkeys(COUNTERS, 0))}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}))

@@ -154,7 +154,18 @@ def _rows(mat, x):
 
 def _iterate(state: SolverState, static, cfg: ParaTAAConfig,
              eps_fn) -> SolverState:
-    """One Algorithm-1 iteration.  Returns the new state."""
+    """One Algorithm-1 iteration.  Returns the new state.
+
+    Named scopes split the iteration in the HLO metadata: ``parataa/
+    denoise`` (the window's eps evaluation), ``residual`` (F, R, the
+    first-order residuals and the window bookkeeping), ``anderson`` (the
+    histories, the safeguard and the accelerated update) and ``pins`` (the
+    replicate pins, where a time mesh puts its all-gather)."""
+    with jax.named_scope("parataa"):
+        return _iterate_scoped(state, static, cfg, eps_fn)
+
+
+def _iterate_scoped(state, static, cfg, eps_fn):
     T, w = static["T"], static["w"]
     x, e, xi = state.x, state.e, state.xi
     D = x.shape[1]
@@ -168,74 +179,88 @@ def _iterate(state: SolverState, static, cfg: ParaTAAConfig,
     # rows.  The downstream replicate pins (e, R, the updated rows) make the
     # collective back an all-gather — exact, so bitwise vs unsharded.
     ta = cfg.time_axis
-    xs = jax.lax.dynamic_slice(x, (t1 + 1, 0), (w, D))
-    taus_w = jax.lax.dynamic_slice(static["taus"], (t1 + 1,), (w,))
-    xs = window_constrain(xs, ta)
-    taus_w = window_constrain(taus_w, ta)
-    e_w = window_constrain(eps_fn(xs, taus_w).astype(e.dtype), ta)
-    e = jax.lax.dynamic_update_slice(e, e_w, (t1 + 1, 0))
-    e = window_constrain(e, ta, replicate=True)
+    with jax.named_scope("denoise"):
+        xs = jax.lax.dynamic_slice(x, (t1 + 1, 0), (w, D))
+        taus_w = jax.lax.dynamic_slice(static["taus"], (t1 + 1,), (w,))
+        xs = window_constrain(xs, ta)
+        taus_w = window_constrain(taus_w, ta)
+        e_w = window_constrain(eps_fn(xs, taus_w).astype(e.dtype), ta)
+        e = jax.lax.dynamic_update_slice(e, e_w, (t1 + 1, 0))
+    with jax.named_scope("pins"):
+        e = window_constrain(e, ta, replicate=True)
 
-    # --- update residual R = F^(k)(x, e) - x (rows 0..T-1) ------------------
-    # lift_k/weps_k contract OVER rows (triangular system) — replicated.
-    F = _rows(static["lift_k"], x) + _rows(static["weps_k"], e) \
-        + state.noise_k
-    R = window_constrain(F - x[:T].astype(jnp.float32), ta, replicate=True)
+    with jax.named_scope("residual"):
+        # --- update residual R = F^(k)(x, e) - x (rows 0..T-1) --------------
+        # lift_k/weps_k contract OVER rows (triangular system) — replicated.
+        F = _rows(static["lift_k"], x) + _rows(static["weps_k"], e) \
+            + state.noise_k
+        R = F - x[:T].astype(jnp.float32)
+    with jax.named_scope("pins"):
+        R = window_constrain(R, ta, replicate=True)
 
-    # --- lines 4-9: first-order residuals, window bookkeeping ---------------
-    # Deviation from Algorithm 1 (robustness fix, see DESIGN §7): rows above
-    # t2 are NOT hard-frozen — they keep taking the (cheap, eps-free) F^(k)
-    # polish with their stored e.  The k-th order system with FIXED e is
-    # linear-triangular and exactly first-order-consistent at its fixed
-    # point, so converged rows stay converged, while hard-freezing them at
-    # threshold-level error can deadlock lower rows whose (smaller)
-    # thresholds sit below the inherited error.  eps evaluations are still
-    # confined to the window — the compute saving is unchanged.
-    r = first_order_residuals((static["a"], static["b"], static["c"]), x, e, xi)
-    rows = jnp.arange(T)
-    active = rows >= t1
-    conv = r <= state.thresh
-    unconv = active & ~conv
-    any_unconv = jnp.any(unconv)
-    # highest unconverged active row
-    new_t2_active = T - 1 - jnp.argmax(jnp.flip(unconv))
-    # all active rows converged: done if t1 == 0, else slide the window down
-    new_t2 = jnp.where(any_unconv, new_t2_active,
-                       jnp.where(t1 == 0, jnp.int32(-1), t1 - 1))
-    done = new_t2 < 0
-    new_t1 = jnp.maximum(0, new_t2 - w + 1)
-    upd_mask = (rows >= new_t1) & ~done
+    with jax.named_scope("residual"):
+        # --- lines 4-9: first-order residuals, window bookkeeping -----------
+        # Deviation from Algorithm 1 (robustness fix, see DESIGN §7): rows
+        # above t2 are NOT hard-frozen — they keep taking the (cheap,
+        # eps-free) F^(k) polish with their stored e.  The k-th order
+        # system with FIXED e is linear-triangular and exactly
+        # first-order-consistent at its fixed point, so converged rows stay
+        # converged, while hard-freezing them at threshold-level error can
+        # deadlock lower rows whose (smaller) thresholds sit below the
+        # inherited error.  eps evaluations are still confined to the
+        # window — the compute saving is unchanged.
+        r = first_order_residuals((static["a"], static["b"], static["c"]),
+                                  x, e, xi)
+        rows = jnp.arange(T)
+        active = rows >= t1
+        conv = r <= state.thresh
+        unconv = active & ~conv
+        any_unconv = jnp.any(unconv)
+        # highest unconverged active row
+        new_t2_active = T - 1 - jnp.argmax(jnp.flip(unconv))
+        # all active rows converged: done if t1 == 0, else slide the window
+        # down
+        new_t2 = jnp.where(any_unconv, new_t2_active,
+                           jnp.where(t1 == 0, jnp.int32(-1), t1 - 1))
+        done = new_t2 < 0
+        new_t1 = jnp.maximum(0, new_t2 - w + 1)
+        upd_mask = (rows >= new_t1) & ~done
 
-    # --- histories (Sec. 3 notation): write dF[(i-1) % m] = R^i - R^{i-1} ---
-    it = state.it
-    m = cfg.history_m
-    dF = state.dF
-    slot_prev = jnp.maximum(it - 1, 0) % m
-    dF_entry = jnp.where(it >= 1, R - state.R_prev, jnp.zeros_like(R))
-    dF = jax.lax.dynamic_update_index_in_dim(dF, dF_entry.astype(dF.dtype), slot_prev, 0)
+    with jax.named_scope("anderson"):
+        # --- histories (Sec. 3 notation): write dF[(i-1) % m] = R^i - R^{i-1}
+        it = state.it
+        m = cfg.history_m
+        dF = state.dF
+        slot_prev = jnp.maximum(it - 1, 0) % m
+        dF_entry = jnp.where(it >= 1, R - state.R_prev, jnp.zeros_like(R))
+        dF = jax.lax.dynamic_update_index_in_dim(
+            dF, dF_entry.astype(dF.dtype), slot_prev, 0)
 
-    # --- lines 10-11: accelerated update over the (new) window --------------
-    guard = None
-    if cfg.safeguard:
-        # rows whose entire suffix has converged (rows above new_t2 are
-        # frozen-converged by construction)
-        conv_or_frozen = conv | (rows > new_t2)
-        suffix_all = jnp.flip(jnp.cumprod(jnp.flip(conv_or_frozen.astype(jnp.int32))))
-        guard = jnp.concatenate([suffix_all[1:] > 0, jnp.array([True])])  # row T-1 suffix empty
-    mode = cfg.mode if cfg.history_m > 1 else "fp"
-    x_rows_new = anderson_update(
-        x[:T], R.astype(x.dtype), state.dX, dF, upd_mask,
-        mode=mode, lam=cfg.lam, safeguard_mask=guard,
-        use_pallas=cfg.use_pallas, interpret=cfg.interpret,
-        time_axis=ta, fuse_round=cfg.fuse_round)
-    x_rows_new = window_constrain(x_rows_new, ta, replicate=True)
+        # --- lines 10-11: accelerated update over the (new) window ----------
+        guard = None
+        if cfg.safeguard:
+            # rows whose entire suffix has converged (rows above new_t2 are
+            # frozen-converged by construction)
+            conv_or_frozen = conv | (rows > new_t2)
+            suffix_all = jnp.flip(jnp.cumprod(
+                jnp.flip(conv_or_frozen.astype(jnp.int32))))
+            # row T-1's suffix is empty
+            guard = jnp.concatenate([suffix_all[1:] > 0, jnp.array([True])])
+        mode = cfg.mode if cfg.history_m > 1 else "fp"
+        x_rows_new = anderson_update(
+            x[:T], R.astype(x.dtype), state.dX, dF, upd_mask,
+            mode=mode, lam=cfg.lam, safeguard_mask=guard,
+            use_pallas=cfg.use_pallas, interpret=cfg.interpret,
+            time_axis=ta, fuse_round=cfg.fuse_round)
+    with jax.named_scope("pins"):
+        x_rows_new = window_constrain(x_rows_new, ta, replicate=True)
 
-    x_new = jnp.concatenate([x_rows_new, x[T:]], axis=0)
-
-    # write dX[i % m] = x^{i+1} - x^i
-    slot = it % m
-    dX = jax.lax.dynamic_update_index_in_dim(
-        state.dX, (x_new[:T] - x[:T]).astype(state.dX.dtype), slot, 0)
+    with jax.named_scope("anderson"):
+        x_new = jnp.concatenate([x_rows_new, x[T:]], axis=0)
+        # write dX[i % m] = x^{i+1} - x^i
+        slot = it % m
+        dX = jax.lax.dynamic_update_index_in_dim(
+            state.dX, (x_new[:T] - x[:T]).astype(state.dX.dtype), slot, 0)
 
     return dataclasses.replace(
         state, x=x_new, e=e, R_prev=R, dX=dX, dF=dF,
@@ -249,11 +274,17 @@ def _seq_iterate(state: SolverState, static, cfg: ParaTAAConfig,
     x[t2+1], write x[t2], slide t2 down.  Bitwise-identical math to
     ``repro.diffusion.samplers._sequential_sample`` (same a/b/c recursion),
     but resumable/chunkable like the parallel iterate."""
+    with jax.named_scope("parataa"):
+        return _seq_iterate_scoped(state, static, eps_fn)
+
+
+def _seq_iterate_scoped(state, static, eps_fn):
     D = state.x.shape[1]
     t = state.t2 + 1                               # current timestep T..1
     x_t = jax.lax.dynamic_slice(state.x, (t, 0), (1, D))
     tau_t = jax.lax.dynamic_slice(static["taus"], (t,), (1,))
-    e = eps_fn(x_t, tau_t)
+    with jax.named_scope("denoise"):
+        e = eps_fn(x_t, tau_t)
     a_t = jax.lax.dynamic_slice(static["a"], (t,), (1,))
     b_t = jax.lax.dynamic_slice(static["b"], (t,), (1,))
     c_prev = jax.lax.dynamic_slice(static["c"], (t - 1,), (1,))

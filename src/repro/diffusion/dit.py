@@ -72,38 +72,59 @@ def _dit_attention(p, x):
 
 def dit_apply(params, cfg: ArchConfig, latents, t, y=None, *, remat: bool = False):
     """eps prediction.  latents: (B, N, latent_dim); t: (B,) float timesteps;
-    y: (B,) int class labels (None -> unconditional bucket)."""
+    y: (B,) int class labels (None -> unconditional bucket).
+
+    Named scopes put each part's ops under ``dit/<part>`` in the HLO
+    metadata (``op_name``), so a device trace splits the step by part:
+    ``embed`` (input projection, positions, t/y conditioning), ``weights``
+    (slicing layer i out of the stacked block weights), ``ada`` (adaLN:
+    the conditioning projection, layer norm and modulation), ``attn``
+    (q/k/v/o projections, scores, softmax, context and the gated
+    residual), ``mlp`` (the MLP and its gated residual), ``final``."""
+    with jax.named_scope("dit"):
+        return _dit_apply(params, cfg, latents, t, y, remat=remat)
+
+
+def _dit_apply(params, cfg: ArchConfig, latents, t, y, *, remat: bool):
     b, n, _ = latents.shape
     d = cfg.d_model
-    x = latents @ params["in_proj"]
-    pos = jnp.asarray(sincos_positions(n, d), x.dtype)
-    x = x + pos[None]
-    x = constrain(x, "batch", None, None)
+    with jax.named_scope("embed"):
+        x = latents @ params["in_proj"]
+        pos = jnp.asarray(sincos_positions(n, d), x.dtype)
+        x = x + pos[None]
+        x = constrain(x, "batch", None, None)
 
-    temb = sinusoidal_embed(t, TEMB_DIM).astype(x.dtype)
-    cond = jax.nn.silu(temb @ params["t_mlp1"]) @ params["t_mlp2"]
-    if y is None:
-        y = jnp.full((b,), cfg.num_classes, jnp.int32)  # null class
-    cond = cond + jnp.take(params["y_embed"], y, axis=0)
-    cond = jax.nn.silu(cond)
+        temb = sinusoidal_embed(t, TEMB_DIM).astype(x.dtype)
+        cond = jax.nn.silu(temb @ params["t_mlp1"]) @ params["t_mlp2"]
+        if y is None:
+            y = jnp.full((b,), cfg.num_classes, jnp.int32)  # null class
+        cond = cond + jnp.take(params["y_embed"], y, axis=0)
+        cond = jax.nn.silu(cond)
 
     def block(p, x):
-        ada = cond @ p["ada"]  # (B, 6d)
-        s1, sc1, g1, s2, sc2, g2 = jnp.split(ada, 6, axis=-1)
-        h = _dit_attention(p, _modulate(layernorm_noaffine(x), s1, sc1))
-        x = x + g1[:, None, :] * h
-        h = mlp(p["mlp"], _modulate(layernorm_noaffine(x), s2, sc2), "gelu")
-        return x + g2[:, None, :] * h
+        with jax.named_scope("ada"):
+            ada = cond @ p["ada"]  # (B, 6d)
+            s1, sc1, g1, s2, sc2, g2 = jnp.split(ada, 6, axis=-1)
+            h = _modulate(layernorm_noaffine(x), s1, sc1)
+        with jax.named_scope("attn"):
+            x = x + g1[:, None, :] * _dit_attention(p, h)
+        with jax.named_scope("ada"):
+            h = _modulate(layernorm_noaffine(x), s2, sc2)
+        with jax.named_scope("mlp"):
+            return x + g2[:, None, :] * mlp(p["mlp"], h, "gelu")
 
     # python loop (unrolled HLO): DiT is small enough, and unrolled layers
     # are counted exactly by the dry-run's cost analysis
     fn = jax.checkpoint(block) if remat else block
     for i in range(cfg.num_layers):
-        x = fn(jax.tree.map(lambda t: t[i], params["blocks"]), x)
-    fa = cond @ params["final_ada"]
-    sh, sc = jnp.split(fa, 2, axis=-1)
-    x = _modulate(layernorm_noaffine(x), sh, sc)
-    return x @ params["out_proj"]
+        with jax.named_scope("weights"):
+            p = jax.tree.map(lambda t: t[i], params["blocks"])
+        x = fn(p, x)
+    with jax.named_scope("final"):
+        fa = cond @ params["final_ada"]
+        sh, sc = jnp.split(fa, 2, axis=-1)
+        x = _modulate(layernorm_noaffine(x), sh, sc)
+        return x @ params["out_proj"]
 
 
 def dit_loss(params, cfg: ArchConfig, batch, abar_full):

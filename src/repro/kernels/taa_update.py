@@ -29,6 +29,9 @@ the Grams are SPD + ridge) run on the resident tile.  The rest of phase 1
 is the apply sweep, which reads the gammas from VMEM.  Launches per round
 go from 3 (gram + host solve + apply) to 1, and the G/u/gamma intermediates
 never touch HBM or the host.
+
+Each ``pallas_call`` is named after its entry point (``taa_gram``,
+``taa_apply``, ``taa_round``): the kernel's name in the lowered program.
 """
 from __future__ import annotations
 
@@ -131,6 +134,7 @@ def taa_gram(dF, R, mask, *, bd: int = 512, interpret: bool = False):
         out_specs=pl.BlockSpec((t, _LANES), lambda di: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((t, _LANES), jnp.float32),
         interpret=interpret,
+        name="_taa_gram",
     )(dF, R, mask.astype(jnp.float32).reshape(t, 1))
     return gu[:, :m * m].reshape(t, m, m), gu[:, m * m:m * m + m]
 
@@ -161,6 +165,7 @@ def taa_apply(x, R, dX, dF, gamma, mask, *, bd: int = 512,
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((t, dpad), x.dtype),
         interpret=interpret,
+        name="_taa_apply",
     )(x, R, dX, dF, gam, mask.astype(jnp.float32).reshape(t, 1))
     return out[:, :d]
 
@@ -272,6 +277,7 @@ def taa_round(x, R, dX, dF, mask, guard, *, mode: str = "taa",
         scratch_shapes=[pltpu.VMEM((t, _LANES), jnp.float32),
                         pltpu.VMEM((t, _LANES), jnp.float32)],
         interpret=interpret,
+        name="_taa_round",
     )(x, R, dX, dF, mask.astype(jnp.float32).reshape(t, 1),
       guard.astype(jnp.float32).reshape(t, 1))
     return out[:, :d]

@@ -64,6 +64,7 @@ solves in a fraction of the cold iteration count:
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import sys
 
@@ -116,7 +117,8 @@ from repro.core import ddim_coeffs, ddpm_coeffs
 from repro.diffusion import dit as dit_mod
 from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_mesh, mesh_names
-from repro.obs import Observability
+from repro.obs import (Observability, compile_totals, count_compiles,
+                       json_safe)
 from repro.runtime import StragglerMitigator
 from repro.sampling import (Placement, SampleRequest, SamplingEngine,
                             get_sampler)
@@ -260,6 +262,7 @@ def serve_async(args, cfg, params, placement: Placement):
     # tools/stepwise_guard.py --phase obs.
     obs = Observability.enabled() if getattr(args, "trace_out", None) \
         else Observability()
+    compiles = count_compiles(obs.metrics).counter("jax.compiles")
     if args.refine:
         if not args.chunk_iters:
             raise SystemExit("--refine requires --chunk-iters > 0 "
@@ -309,6 +312,7 @@ def serve_async(args, cfg, params, placement: Placement):
         print(f"warmed {key.describe()}: {engine.placement.describe()}, "
               f"{warm[key]} program(s) compiled")
 
+    warm_compiles = compiles.series()
     rng = np.random.default_rng(args.seed)
     gaps = simulate_arrivals(rng, args.requests, args.arrival_rate)
     tickets = []
@@ -376,6 +380,10 @@ def serve_async(args, cfg, params, placement: Placement):
           f"mean NFE/request {np.mean([r.nfe for r in results]):.0f}; "
           f"{n_early} early-exit(s); {sum(retraces.values())} program(s) "
           f"compiled after warm-up; loop stats {loop.stats}")
+    late = {fun.partition("=")[2]: n - warm_compiles.get(fun, 0)
+            for fun, n in compiles.series().items()
+            if n > warm_compiles.get(fun, 0)}
+    print(f"jax compiles after warm-up, by function: {late or 'none'}")
     if args.chaos_drop:
         res = loop.resilience
         unresolved = [t for t in tickets if not t.done()]
@@ -413,6 +421,9 @@ def serve_async(args, cfg, params, placement: Placement):
                   f"({c['bytes']} B)")
     if getattr(args, "trace_out", None):
         path = obs.tracer.export(args.trace_out)
+        snap = path.with_suffix(".metrics.json")
+        snap.write_text(json.dumps(json_safe(obs.metrics.snapshot())))
+        print(f"metrics: {len(obs.metrics.names())} instrument(s) -> {snap}")
         curves = sum(1 for t in tickets if t.residual_curve)
         wait = obs.metrics.histogram("loop.queue_wait_s").merged() \
             or {"p50": 0.0, "p95": 0.0}
@@ -543,7 +554,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "loadable) of the --serve-async drain: per-ticket "
                         "submit->resolve span chains, engine pack/dispatch/"
                         "stepwise spans, and per-lane residual-vs-round "
-                        "convergence curves (see tools/obs_report.py)")
+                        "convergence curves (see tools/obs_report.py); "
+                        "the metrics registry's snapshot goes beside it "
+                        "(PATH with suffix .metrics.json)")
     p.add_argument("--ckpt", default=None, help="trained DiT checkpoint dir")
     p.add_argument("--seed", type=int, default=0)
     return p
@@ -584,6 +597,7 @@ def main(argv=None):
     engine = make_engine(params, cfg, coeffs,
                          resolve_spec(args, args.solver), placement=placement)
 
+    metrics = count_compiles(engine.obs.metrics)
     outs, stats, straggler = serve_batch(
         engine, make_requests(args, cfg), batch_size=args.batch_size or None)
     for st in stats:
@@ -601,6 +615,8 @@ def main(argv=None):
           f"({engine.stats['requests']} requests / "
           f"{engine.stats['batches']} batches, "
           f"{engine.stats['traces']} compilation(s))")
+    print("jax: " + ", ".join(f"{name} {n:.0f}" for name, n in
+                              compile_totals(metrics).items()))
     return outs, stats
 
 

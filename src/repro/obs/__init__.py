@@ -4,15 +4,18 @@ Three pillars, one facade:
 
   * :mod:`repro.obs.metrics` — a typed, thread-safe
     :class:`MetricsRegistry` (Counter/Gauge/Histogram with label sets,
-    ``snapshot()``/``delta()``) every serving layer registers into; the
-    legacy ``stats`` dicts stay available verbatim as
-    :class:`StatsView`\\ s mirroring into it.
-  * :mod:`repro.obs.trace` — :class:`SpanTracer`: monotonic-clock span
-    tracing (engine pack/dispatch/collect and stepwise
-    open/refill/step/poll/harvest/gather spans; per-ticket
-    submit -> validate -> admit -> splice -> draft -> refine-resubmit ->
-    resolve lifecycle spans) with Chrome-trace-event JSON export
-    (``serve.py --trace-out trace.json`` loads in Perfetto).
+    ``snapshot()``) every serving layer registers into; the legacy
+    ``stats`` dicts stay available verbatim as :class:`StatsView`\\ s
+    mirroring into it.  :func:`count_compiles` adds JAX's own trace,
+    compile and persistent-cache-hit events to a registry, by function.
+  * :mod:`repro.obs.trace` — :class:`SpanTracer`: span tracing (engine
+    pack/dispatch/collect, stepwise open/refill/step/poll/harvest/gather
+    and serving-loop admit/idle spans; per-ticket submit -> validate ->
+    admit -> splice -> draft -> refine-resubmit -> resolve lifecycle
+    spans).  Every span lands on the profiler's host trace, on the device
+    trace's clock, whenever a ``jax.profiler`` session is open; an enabled
+    tracer also keeps Chrome-trace-event JSON (``serve.py --trace-out
+    trace.json`` loads in Perfetto).
   * :mod:`repro.obs.convergence` — :class:`ConvergenceRecorder`:
     per-lane, per-round fixed-point residual curves, fed by the residual
     column the stepwise step program piggybacks onto its packed poll
@@ -24,13 +27,15 @@ PROTOCOL-NEUTRAL — an enabled Observability changes no compiled program
 count (still exactly 5 stepwise traces), no blocking-poll or host-fetch
 accounting, and no solve bit.  ``Observability.off()`` (what every
 component defaults to) keeps a working private metrics registry and a
-no-op tracer, so instrumented code never branches on "is obs on".
+tracer that records no JSON (its spans still reach a profiler session),
+so instrumented code never branches on "is obs on".
 """
 from __future__ import annotations
 
 import time
 from typing import Callable, Optional
 
+from repro.obs.compiles import compile_totals, count_compiles
 from repro.obs.convergence import ConvergenceRecorder
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                StatsView)
@@ -41,6 +46,7 @@ __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "StatsView",
     "SpanTracer", "json_safe",
     "ConvergenceRecorder",
+    "count_compiles", "compile_totals",
 ]
 
 
@@ -82,7 +88,7 @@ class Observability:
     def off(cls) -> "Observability":
         """A private, tracing-disabled bundle — the default every
         component constructs for itself when none is wired in, so
-        un-instrumented usage needs no conditionals and pays no tracing
-        cost (each instance gets its OWN registry; label collisions
+        un-instrumented usage needs no conditionals and pays no JSON
+        tracing cost (each instance gets its OWN registry; label collisions
         between unrelated components cannot happen)."""
         return cls()

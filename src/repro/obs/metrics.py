@@ -5,8 +5,7 @@ register — the engine, ``LaneBank`` (via the engine's counters), the
 ``ServingLoop``, ``RequestQueue``, ``Batcher``, and ``TrajectoryCache`` all
 write into the same registry when wired through one
 :class:`~repro.obs.Observability` — so a single ``snapshot()`` answers
-"what did this process do" and ``delta(prev)`` answers "what did it do
-since the last look".
+"what did this process do".
 
 Three instrument types, each supporting label sets (labels are passed as
 keyword arguments on every update; each distinct label set is its own
@@ -215,8 +214,7 @@ class MetricsRegistry:
     ``counter``/``gauge``/``histogram`` create-or-return the named
     instrument (re-registering a name under a different type is an error —
     a silent type change would corrupt dashboards).  ``snapshot()`` walks
-    every series; ``delta(prev)`` subtracts a previous snapshot so callers
-    can meter an interval without resetting anything.
+    every series.
     """
 
     def __init__(self):
@@ -253,36 +251,6 @@ class MetricsRegistry:
         with self._lock:
             metrics = list(self._metrics.values())
         return {m.name: m.series() for m in metrics}
-
-    def delta(self, prev: Dict[str, Dict]) -> Dict[str, Dict]:
-        """Current snapshot minus ``prev`` (a prior ``snapshot()``).
-
-        Scalars subtract; histogram exports subtract field-wise (min/max
-        are NOT interval-scoped, so they pass through current values).
-        Series absent from ``prev`` report their full current value.
-        """
-        out: Dict[str, Dict] = {}
-        for name, series in self.snapshot().items():
-            prev_series = prev.get(name, {})
-            out[name] = {key: _sub(value, prev_series.get(key))
-                         for key, value in series.items()}
-        return out
-
-
-def _sub(cur, old):
-    if old is None:
-        return cur
-    if isinstance(cur, dict):
-        out = dict(cur)
-        for field in ("count", "sum"):
-            if field in out and field in old:
-                out[field] = out[field] - old[field]
-        if "bucket_counts" in out and "bucket_counts" in old:
-            out["bucket_counts"] = [c - o for c, o in
-                                    zip(out["bucket_counts"],
-                                        old["bucket_counts"])]
-        return out
-    return cur - old
 
 
 class StatsView(dict):

@@ -1,29 +1,41 @@
-"""Monotonic-clock span tracing with Chrome-trace-event JSON export.
+"""Span tracing with two sinks: the profiler's host trace, and
+Chrome-trace-event JSON.
 
 One :class:`SpanTracer` is shared by every serving layer (via
 :class:`~repro.obs.Observability`).  Two event families cover the stack:
 
   * COMPLETE spans (``span(...)`` context manager, phase ``"X"``) for
     engine work units — pack/dispatch/collect on the whole-batch path,
-    stepwise open/refill/step/poll/harvest/gather per round — each on a
-    per-engine track (``tid``);
+    stepwise open/refill/step/poll/harvest/gather per round, the serving
+    loop's admission and idle waits — each on a per-engine track
+    (``tid``);
   * NESTABLE ASYNC spans (``async_begin``/``async_instant``/``async_end``,
     phases ``"b"``/``"n"``/``"e"``) for ticket lifecycles: one span per
     ticket seqno running submit -> resolve, with instant markers for
     validate/admit/splice/draft/refine-resubmit/preempt along the way and
     the final event carrying the ticket's per-round residual curve.
 
-Timestamps come from ``time.monotonic()`` (never wall clock — NTP steps
-would fold spans backward) relative to the tracer's construction, exported
-in microseconds per the Chrome trace-event spec, so ``export(path)``
-writes a file Perfetto / ``chrome://tracing`` loads directly
-(``serve.py --trace-out trace.json``).
+Profiler sink: every ``span`` opens a ``jax.profiler.TraceAnnotation`` of
+the span's name, enabled tracer or not.  With no profiler session that
+costs about a microsecond; inside one (``jax.profiler.trace``) the span
+lands on the ``/host:CPU`` plane on the same clock as the device's ``XLA
+Ops``, so a device idle gap can be named after the program span the host
+was in.  Only ``span`` has this sink: lifecycle spans and instants are
+JSON-only.
+
+JSON sink: only an enabled tracer records.  Timestamps come from
+``time.monotonic()`` (never wall clock — NTP steps would fold spans
+backward) relative to the tracer's construction, exported in microseconds
+per the Chrome trace-event spec, so ``export(path)`` writes a file
+Perfetto / ``chrome://tracing`` loads directly (``serve.py --trace-out
+trace.json``).
 
 A disabled tracer (``SpanTracer(enabled=False)``, the default everywhere
-an :class:`~repro.obs.Observability` was not explicitly enabled) no-ops
-every call: instrumented code never branches on whether tracing is on.
-Event storage is bounded (``max_events``); overflow drops new events and
-counts them (``dropped``) instead of growing without bound on long soaks.
+an :class:`~repro.obs.Observability` was not explicitly enabled) records
+no JSON event and formats no args: instrumented code never branches on
+whether tracing is on.  Event storage is bounded (``max_events``);
+overflow drops new events and counts them (``dropped``) instead of
+growing without bound on long soaks.
 """
 from __future__ import annotations
 
@@ -34,6 +46,8 @@ import threading
 import time
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["SpanTracer", "json_safe"]
 
@@ -67,8 +81,9 @@ def json_safe(value):
 class SpanTracer:
     """Thread-safe span recorder in Chrome trace-event form.
 
-    enabled:    False makes every method a cheap no-op (the default wiring
-                for un-instrumented runs).
+    enabled:    False records no JSON event (the default wiring for
+                un-instrumented runs); ``span`` still annotates the
+                profiler's host trace.
     clock:      monotonic timestamp source (injectable for deterministic
                 tests, mirroring the queue's pattern).
     max_events: bound on stored events; overflow counts into ``dropped``.
@@ -108,22 +123,26 @@ class SpanTracer:
 
     # -- complete spans ------------------------------------------------------
 
-    @contextlib.contextmanager
     def span(self, name: str, *, cat: str = "span", tid: str = "main",
              **args):
-        """Record one complete ("X") span around the with-block."""
+        """A context manager around one complete ("X") span: always on the
+        profiler's host trace, and as a JSON event when enabled."""
         if not self.enabled:
-            yield
-            return
-        t0 = self.clock()
-        try:
-            yield
-        finally:
-            ts = self._ts_us(t0)
-            self._emit({"name": name, "cat": cat, "ph": "X", "ts": ts,
-                        "dur": self._ts_us() - ts, "pid": 1,
-                        "tid": self._tid(tid),
-                        "args": json_safe(args) if args else {}})
+            return TraceAnnotation(name)
+        return self._recorded_span(name, cat, tid, args)
+
+    @contextlib.contextmanager
+    def _recorded_span(self, name: str, cat: str, tid: str, args: Dict):
+        with TraceAnnotation(name):
+            t0 = self.clock()
+            try:
+                yield
+            finally:
+                ts = self._ts_us(t0)
+                self._emit({"name": name, "cat": cat, "ph": "X", "ts": ts,
+                            "dur": self._ts_us() - ts, "pid": 1,
+                            "tid": self._tid(tid),
+                            "args": json_safe(args) if args else {}})
 
     def instant(self, name: str, *, cat: str = "span", tid: str = "main",
                 **args) -> None:

@@ -827,7 +827,11 @@ class SamplingEngine:
         iterations (non-blocking: JAX async dispatch) and start the
         piggybacked (slots, 5) scheduling summary's device->host copy —
         by the time the NEXT round's harvest polls, the bytes are already
-        on the host and the ``device_get`` returns without stalling."""
+        on the host and the ``device_get`` returns without stalling.
+
+        The ``stepwise.step`` span covers the enqueue only: the chunk runs
+        on the device after it closes, and the next ``stepwise.poll``
+        span is where the host waits for it."""
         with self._tracer.span("stepwise.step", tid=self.name,
                                chunk_iters=bank.chunk_iters,
                                occupied=bank.occupied):

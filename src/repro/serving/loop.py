@@ -330,9 +330,10 @@ class ServingLoop:
                 extra = min(len(background),
                             max(self.queue.pending_urgent(key)
                                 - len(free), 0))
-                admit = self.batcher.plan_refill(
-                    self.queue, key, len(free) + extra, now=now,
-                    active=bank.occupied > 0, flush=flush)
+                with self.obs.tracer.span("loop.admit", tid="loop"):
+                    admit = self.batcher.plan_refill(
+                        self.queue, key, len(free) + extra, now=now,
+                        active=bank.occupied > 0, flush=flush)
                 for lane in background[:max(len(admit) - len(free), 0)]:
                     self._preempt(key, bank, tickets, lane)
                 admitted += self._refill(engine, bank, tickets,
@@ -506,7 +507,9 @@ class ServingLoop:
                             # (and the next harvest blocks on that chunk);
                             # only a fully idle loop needs to sleep
                             if not self._occupied_lanes():
-                                self._stop_event.wait(poll_s)
+                                with self.obs.tracer.span("loop.idle",
+                                                          tid="loop"):
+                                    self._stop_event.wait(poll_s)
                             continue
                         # never park in a blocking collect here: collect
                         # any batch that already finished on device (out of

@@ -1,19 +1,24 @@
 """`repro.obs` coverage: the metrics registry and StatsView bridge, the
-span tracer's Chrome-trace export, the convergence recorder, the engine's
+span tracer's Chrome-trace export and profiler sink, the compile counters,
+the named scopes of the step program, the convergence recorder, the engine's
 injectable monotonic clock, and the serving-stack integration — traced
 drains must leave every ticket a complete span chain plus a residual
 curve while changing nothing about the solves or the host protocol
 (`tools/stepwise_guard.py --phase obs` enforces the protocol half in CI;
 these tests cover the semantics)."""
+import glob
 import json
 import math
+import os
+import re
 
 import numpy as np
 import pytest
 
 from repro.core import ddim_coeffs
 from repro.obs import (ConvergenceRecorder, MetricsRegistry, Observability,
-                       SpanTracer, StatsView, json_safe)
+                       SpanTracer, StatsView, compile_totals, count_compiles,
+                       json_safe)
 from repro.sampling import SampleRequest, SamplingEngine, get_sampler
 from repro.serving import (Batcher, BatchingPolicy, EngineKey, EngineRegistry,
                            RefinePlanner, RefinePolicy, RequestQueue,
@@ -84,12 +89,13 @@ def test_snapshot_and_delta():
     before = reg.snapshot()
     reg.counter("n").inc(2)
     reg.histogram("h").observe(2.0)
-    d = reg.delta(before)
-    assert d["n"][""] == 2
-    assert d["h"][""]["count"] == 1 and d["h"][""]["sum"] == 2.0
-    # series absent from prev report their full value
+    after = reg.snapshot()
+    # a snapshot is a copy: later updates leave it as it was
+    assert before["n"][""] == 3 and before["h"][""]["count"] == 1
+    assert after["n"][""] == 5
+    assert after["h"][""]["count"] == 2 and after["h"][""]["sum"] == 3.0
     reg.counter("new").inc(7)
-    assert reg.delta(before)["new"][""] == 7
+    assert "new" not in after and reg.snapshot()["new"][""] == 7
 
 
 def test_stats_view_is_a_dict_and_mirrors_into_gauges():
@@ -384,3 +390,80 @@ def test_failed_ticket_closes_span_and_discards_curve():
     assert obs.metrics.counter("queue.rejected").value(
         key=key.describe()) == 1
     assert obs.convergence.open_curves() == 0
+
+
+# --- profiler sink, compile counters, named scopes ---------------------------
+
+
+def _profiler_host_events(trace_dir) -> set:
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    profile = ProfileData.from_file(path)
+    return {e.name for plane in profile.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events}
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_span_lands_on_the_profiler_host_plane(tmp_path, enabled):
+    """Inside a profiler session every span reaches the /host:CPU plane
+    under its own name; only an enabled tracer also keeps a JSON event."""
+    import jax
+    tracer = SpanTracer(enabled=enabled)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracer.span("stepwise.poll", tid="engine-a", occupied=3):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert "stepwise.poll" in _profiler_host_events(str(tmp_path))
+    assert [e["name"] for e in tracer.events()] == \
+        (["stepwise.poll"] if enabled else [])
+
+
+def test_compile_counters_name_the_function():
+    import jax
+    import jax.numpy as jnp
+    reg = count_compiles(MetricsRegistry())
+    count_compiles(reg)                 # a second call adds no listener
+    before = compile_totals(reg)
+
+    def obs_counted_fn(x):
+        return x * 3 + 1
+
+    jax.jit(obs_counted_fn)(jnp.arange(3.0))
+    compiles = reg.counter("jax.compiles")
+    assert compiles.value(fun_name="jit(obs_counted_fn)") == 1
+    assert reg.counter("jax.traces").value(fun_name="obs_counted_fn") == 1
+    after = compile_totals(reg)
+    assert after["jax.compiles"] - before["jax.compiles"] >= 1
+    assert set(reg.snapshot()) >= {"jax.traces", "jax.compiles",
+                                   "jax.cache_hits"}
+
+
+@pytest.mark.parametrize("solver,scopes", [
+    ("taa", ("dit/attn", "dit/mlp", "dit/ada", "dit/weights",
+             "parataa/denoise", "parataa/residual", "parataa/anderson")),
+    ("seq", ("dit/attn", "dit/mlp", "parataa/denoise")),
+])
+def test_step_program_names_its_scopes(solver, scopes):
+    """The stepwise step program's compiled HLO carries the DiT's and the
+    solver's named scopes in its op_name metadata, which is what a device
+    trace attributes op time by."""
+    import jax
+    from repro.configs.registry import ARCHS
+    from repro.diffusion import dit
+    from repro.launch import serve
+    cfg = ARCHS["dit-xl"].reduced()
+    params = dit.dit_init(cfg, jax.random.PRNGKey(0))
+    engine = serve.make_engine(params, cfg, ddim_coeffs(4),
+                               get_sampler(solver))
+    bank = engine.stepwise_open(2, chunk_iters=1)
+    text = engine._stepwise_program("step", 1).lower(
+        engine.params, bank.state, bank.labels).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in scopes:
+        assert any(f"/{scope}/" in n for n in names), scope
+    # the DiT runs inside the solver's denoise scope
+    assert any("/parataa/denoise/dit/attn/" in n for n in names)
+

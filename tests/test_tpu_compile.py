@@ -12,6 +12,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every pytest worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -35,20 +37,24 @@ def one_chip():
         yield SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, one_chip, kernel, *shapes):
+    """Compile ``fn`` vmapped over the slots; its Pallas call carries the
+    name a profiler shows for the kernel."""
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
             for s in shapes]
-    text = jax.jit(jax.vmap(fn)).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    lowered = jax.jit(jax.vmap(fn)).lower(*args)
+    assert f'kernel_name = "{kernel}"' in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert re.search(rf"%\S*{kernel}\S* = .*tpu_custom_call", text)
 
 
 def test_taa_gram_compiles_for_v5e(one_chip):
-    _compile(taa_update.taa_gram, one_chip,
+    _compile(taa_update.taa_gram, one_chip, "_taa_gram",
              (SLOTS, M, T, D), (SLOTS, T, D), (SLOTS, T))
 
 
 def test_taa_apply_compiles_for_v5e(one_chip):
-    _compile(taa_update.taa_apply, one_chip,
+    _compile(taa_update.taa_apply, one_chip, "_taa_apply",
              (SLOTS, T, D), (SLOTS, T, D), (SLOTS, M, T, D),
              (SLOTS, M, T, D), (SLOTS, T, M), (SLOTS, T))
 
@@ -58,6 +64,39 @@ def test_taa_round_compiles_for_v5e(one_chip, mode):
     def fused(x, R, dX, dF, mask, guard):
         return taa_update.taa_round(x, R, dX, dF, mask, guard, mode=mode)
 
-    _compile(fused, one_chip,
+    _compile(fused, one_chip, "_taa_round",
              (SLOTS, T, D), (SLOTS, T, D), (SLOTS, M, T, D),
              (SLOTS, M, T, D), (SLOTS, T), (SLOTS, T))
+
+
+def test_step_program_names_the_taa_kernels(one_chip):
+    """In the engine's stepwise step program the kernels' instructions are
+    named after them (``%_taa_gram.N``, ``%_taa_apply.N``), which is what a
+    device trace shows and what the benchmark's kernel share matches."""
+    from repro.configs.registry import ARCHS
+    from repro.core import ddim_coeffs
+    from repro.diffusion import dit
+    from repro.launch import serve
+    from repro.models.pdefs import is_def
+    from repro.sampling import get_sampler
+    cfg = ARCHS["dit-xl"].reduced()
+    params = jax.tree.map(
+        lambda d: jax.ShapeDtypeStruct(d.shape, jnp.float32,
+                                       sharding=one_chip),
+        dit.dit_defs(cfg), is_leaf=is_def)
+    engine = serve.make_engine(params, cfg, ddim_coeffs(8),
+                               get_sampler("taa", use_pallas=True))
+    xi = jax.ShapeDtypeStruct((9,) + tuple(engine.sample_shape),
+                              jnp.float32)
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(engine._stepwise_program("open", SLOTS), xi))
+    labels = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
+    text = engine._stepwise_program("step", 1).lower(
+        params, state, labels).compile().as_text()
+    kernels = re.findall(r"(%\S+) = \S+ custom-call\([^)]*\), "
+                         r'custom_call_target="tpu_custom_call"', text)
+    assert kernels and all(k.startswith("%_taa_") for k in kernels)
+    assert {re.sub(r"\.\d+$", "", k) for k in kernels} == \
+        {"%_taa_gram", "%_taa_apply"}
+
