@@ -12,9 +12,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.kernels import ops
 from repro.models import pdefs
 from repro.models.pdefs import ParamDef, stack_defs
 from repro.models.layers import (layernorm_noaffine, mlp, mlp_def,
@@ -58,18 +58,6 @@ def _modulate(x, shift, scale):
     return x * (1.0 + scale[:, None, :]) + shift[:, None, :]
 
 
-def _dit_attention(p, x):
-    """Full (non-causal) attention.  x: (B, N, d)."""
-    q = jnp.einsum("bnd,dhk->bnhk", x, p["wq"])
-    k = jnp.einsum("bnd,dhk->bnhk", x, p["wk"])
-    v = jnp.einsum("bnd,dhk->bnhk", x, p["wv"])
-    scores = jnp.einsum("bnhk,bmhk->bhnm", q.astype(jnp.float32), k.astype(jnp.float32))
-    scores = scores / np.sqrt(q.shape[-1])
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bhnm,bmhk->bnhk", probs, v.astype(jnp.float32)).astype(x.dtype)
-    return jnp.einsum("bnhk,hkd->bnd", ctx, p["wo"])
-
-
 def dit_apply(params, cfg: ArchConfig, latents, t, y=None, *, remat: bool = False):
     """eps prediction.  latents: (B, N, latent_dim); t: (B,) float timesteps;
     y: (B,) int class labels (None -> unconditional bucket).
@@ -107,7 +95,8 @@ def _dit_apply(params, cfg: ArchConfig, latents, t, y, *, remat: bool):
             s1, sc1, g1, s2, sc2, g2 = jnp.split(ada, 6, axis=-1)
             h = _modulate(layernorm_noaffine(x), s1, sc1)
         with jax.named_scope("attn"):
-            x = x + g1[:, None, :] * _dit_attention(p, h)
+            x = x + g1[:, None, :] * ops.dit_attention(
+                h, p["wq"], p["wk"], p["wv"], p["wo"])
         with jax.named_scope("ada"):
             h = _modulate(layernorm_noaffine(x), s2, sc2)
         with jax.named_scope("mlp"):

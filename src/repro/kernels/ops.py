@@ -15,7 +15,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref as _ref
-from repro.kernels.flash_attention import flash_attention as _flash_attention
+from repro.kernels.flash_attention import (
+    dit_flash_attention as _dit_flash_attention,
+    flash_attention as _flash_attention)
 from repro.kernels.flash_decode import flash_decode as _flash_decode
 from repro.kernels.ssd_scan import ssd_scan as _ssd_scan
 from repro.kernels.rglru_scan import rglru_scan_kernel as _rglru_scan
@@ -40,6 +42,69 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
         return _flash_attention(q, k, v, causal=causal, window=window,
                                 interpret=interpret)
     return _ref.attention_ref(q, k, v, causal=causal, window=window)
+
+
+def _dit_attention_ref_t(qt, kt, vt):
+    """``ref.dit_attention_ref`` in the kernel's (H, B, K, N) layout."""
+    t = functools.partial(jnp.transpose, axes=(1, 3, 0, 2))
+    ctx = _ref.dit_attention_ref(t(qt), t(kt), t(vt))
+    return jnp.transpose(ctx, (2, 0, 3, 1)).astype(qt.dtype)
+
+
+def _dit_spec(shape):
+    """The kernel's block of (H, B, K, N) on each device of the ambient
+    mesh: heads over `model`, rows over the batch axes.  A serving mesh
+    keeps the batch axes for the request axis; there the rows go over
+    `time` where the mesh has it and it divides them, the rule by which
+    ParaTAA pins its window rows (``window_constrain``), so each time shard
+    attends only its own rows."""
+    from repro.models.shardctx import current_mesh, logical_spec
+    mesh = current_mesh()
+    if mesh is None:
+        return P()
+    heads, rows, _, _ = logical_spec(shape, "heads", "batch", None, None)
+    time = dict(zip(mesh.axis_names, mesh.devices.shape)).get("time")
+    if rows is None and time and shape[1] % time == 0:
+        rows = "time"
+    return P(heads, rows, None, None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dit_flash(qt, kt, vt, interpret):
+    return _per_device(functools.partial(_dit_flash_attention,
+                                         interpret=interpret),
+                       qt, kt, vt, spec=_dit_spec(qt.shape))
+
+
+def _dit_flash_fwd(qt, kt, vt, interpret):
+    return _dit_flash(qt, kt, vt, interpret), (qt, kt, vt)
+
+
+def _dit_flash_bwd(interpret, qkv, g):
+    return jax.vjp(_dit_attention_ref_t, *qkv)[1](g)
+
+
+_dit_flash.defvjp(_dit_flash_fwd, _dit_flash_bwd)
+
+
+def dit_attention(x, wq, wk, wv, wo, *, use_pallas: Optional[bool] = None,
+                  interpret: bool = False):
+    """The DiT's non-causal self-attention.  x: (B, N, d); wq, wk, wv:
+    (d, H, K); wo: (H, K, d) -> (B, N, d).
+
+    On the TPU q, k, v leave their projections as (H, B, K, N), the layout
+    of one Pallas flash kernel (``_dit_flash``) that keeps each score tile
+    in VMEM, at the precision XLA's default matmul gives the einsums (bf16
+    MXU operands, f32 accumulation and softmax); its gradient is the
+    einsums', recomputed from q, k, v.  Elsewhere the f32 einsums
+    (``ref.dit_attention_ref``)."""
+    if _pick(use_pallas):
+        qt, kt, vt = (jnp.einsum("bnd,dhk->hbkn", x, w) for w in (wq, wk, wv))
+        ctx = _dit_flash(qt, kt, vt, interpret).astype(x.dtype)
+        return jnp.einsum("hbkn,hkd->bnd", ctx, wo)
+    q, k, v = (jnp.einsum("bnd,dhk->bnhk", x, w) for w in (wq, wk, wv))
+    ctx = _ref.dit_attention_ref(q, k, v).astype(x.dtype)
+    return jnp.einsum("bnhk,hkd->bnd", ctx, wo)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
@@ -76,12 +141,13 @@ def _row_pin(x, time_axis, dim=0, *, replicate=False):
     return window_constrain(x, time_axis, dim, replicate=replicate)
 
 
-def _per_device(kernel, *args):
+def _per_device(kernel, *args, spec=P()):
     """Run a Pallas kernel call on every device of the ambient serving mesh.
 
     GSPMD cannot partition a Mosaic kernel, so under a mesh the call goes
-    through ``shard_map`` with replicated specs: each device runs it on its
-    own copy of the operands.  Under the engine's
+    through ``shard_map`` with ``spec`` on every operand and the output
+    (replicated by default): each device runs it on its own block of the
+    operands.  Under the engine's
     ``vmap(spmd_axis_name=data)`` the request axis becomes a ``data``-sharded
     dim of that shard_map, so each device runs its own requests; on every
     other mesh axis the per-request operands are whole (the ops below keep
@@ -90,8 +156,8 @@ def _per_device(kernel, *args):
     mesh = current_mesh()
     if mesh is None:
         return kernel(*args)
-    return jax.shard_map(kernel, mesh=mesh, in_specs=(P(),) * len(args),
-                         out_specs=P(), check_vma=False)(*args)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec,) * len(args),
+                         out_specs=spec, check_vma=False)(*args)
 
 
 # Time-sharded dispatch notes (both caught by the bitwise suite):

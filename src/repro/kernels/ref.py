@@ -34,6 +34,16 @@ def attention_ref(q, k, v, *, causal: bool = True, window: int = 0):
     return out.astype(q.dtype)
 
 
+def dit_attention_ref(q, k, v):
+    """The DiT's non-causal attention, f32 scores and softmax.  q, k, v:
+    (B, N, H, K) -> f32 (B, N, H, K)."""
+    scores = jnp.einsum("bnhk,bmhk->bhnm", q.astype(jnp.float32),
+                        k.astype(jnp.float32))
+    scores = scores / np.sqrt(q.shape[-1])
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhnm,bmhk->bnhk", probs, v.astype(jnp.float32))
+
+
 # --- GQA flash decode --------------------------------------------------------
 
 

@@ -93,13 +93,22 @@ def _resolve(logical: Optional[str], dim: int, mesh):
     return None
 
 
+def logical_spec(shape, *logical_axes) -> P:
+    """The PartitionSpec ``constrain`` gives an array of ``shape`` on the
+    ambient mesh (empty without one)."""
+    mesh = _MESH.get()
+    if mesh is None:
+        return P()
+    assert len(logical_axes) == len(shape), (logical_axes, shape)
+    return P(*[_resolve(ax, d, mesh) for ax, d in zip(logical_axes, shape)])
+
+
 def constrain(x, *logical_axes):
     """with_sharding_constraint against the ambient mesh (no-op without one)."""
     mesh = _MESH.get()
     if mesh is None:
         return x
-    assert len(logical_axes) == x.ndim, (logical_axes, x.shape)
-    spec = P(*[_resolve(ax, d, mesh) for ax, d in zip(logical_axes, x.shape)])
+    spec = logical_spec(x.shape, *logical_axes)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 

@@ -1,5 +1,7 @@
 """Per-kernel correctness: shape/dtype sweeps, interpret=True vs pure-jnp
 oracle (ref.py)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +9,8 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels import ops
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (dit_blocks, dit_flash_attention,
+                                           flash_attention)
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.ssd_scan import ssd_scan
 from repro.kernels.rglru_scan import rglru_scan_kernel
@@ -43,6 +46,46 @@ def test_flash_attention_window_changes_output():
     full = flash_attention(q, k, v, causal=True, window=0, interpret=True)
     win = flash_attention(q, k, v, causal=True, window=64, interpret=True)
     assert float(jnp.max(jnp.abs(full - win))) > 1e-3
+
+
+# (slots, H, B, N): G > 1 pairs a block at N = 256, 1024 and 16 tokens
+DIT_SHAPES = [(2, 4, 2, 256), (2, 2, 1, 1024), (1, 2, 3, 16)]
+
+
+@pytest.mark.parametrize("slots,h,b,n", DIT_SHAPES)
+def test_dit_flash_attention_matches_einsum(slots, h, b, n):
+    """The DiT kernel, vmapped over slots as the engine calls it, against
+    the f32 einsum attention: no farther off than twice what rounding q, k,
+    v to bf16 (XLA's default TPU precision) moves the einsums themselves."""
+    assert dit_blocks(h * b, n, 72)[1] > 1
+    qkv = [jax.random.normal(jax.random.fold_in(KEY, i), (slots, h, b, 72, n))
+           for i in range(3)]
+    out = jax.vmap(functools.partial(dit_flash_attention, interpret=True))(*qkv)
+    einsum = jax.vmap(ops._dit_attention_ref_t)
+    want = einsum(*qkv)
+    bf16 = [a.astype(jnp.bfloat16).astype(jnp.float32) for a in qkv]
+    rounding = float(jnp.max(jnp.abs(einsum(*bf16) - want)))
+    err = float(jnp.max(jnp.abs(out - want)))
+    assert 0 < err <= 2 * rounding, (err, rounding)
+    assert out.shape == want.shape and out.dtype == want.dtype
+
+
+@pytest.mark.parametrize("slots,h,b,n", DIT_SHAPES[:2])
+def test_dit_attention_gradient_is_the_einsum_gradient(slots, h, b, n):
+    """The kernel path's custom VJP, vmapped over slots, is the einsum
+    attention's VJP."""
+    qkv = [jax.random.normal(jax.random.fold_in(KEY, i), (slots, h, b, 72, n))
+           for i in range(3)]
+    w = jax.random.normal(jax.random.fold_in(KEY, 9), qkv[0].shape)
+
+    def grad(fn):
+        return jax.grad(lambda *a: jnp.sum(jax.vmap(fn)(*a) * w),
+                        argnums=(0, 1, 2))(*qkv)
+
+    kernel = grad(lambda q, k, v: ops._dit_flash(q, k, v, True))
+    for g, r in zip(kernel, grad(ops._dit_attention_ref_t)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-6, atol=1e-6)
 
 
 @pytest.mark.parametrize("shape", [(2, 8, 4, 512, 64), (3, 16, 2, 1024, 128),

@@ -294,7 +294,7 @@ import json
 import jax, jax.numpy as jnp, numpy as np
 from repro.kernels import ops
 from repro.launch.mesh import make_mesh
-from repro.models.shardctx import serving_mesh
+from repro.models.shardctx import serving_mesh, window_constrain
 
 B, m, T, D = 4, 3, 12, 256
 ks = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -305,16 +305,22 @@ dF = 0.1 * jax.random.normal(ks[3], (B, m, T, D))
 mask = jnp.ones((B, T)).at[:, :2].set(0.0)
 guard = jnp.zeros((B, T), bool).at[:, -2:].set(True)
 gamma = 0.1 * jax.random.normal(ks[0], (B, T, m))
+# DiT attention: 2 window rows of 16 tokens, width 32, 4 heads of 8
+kd = jax.random.split(ks[1], 5)
+h = jax.random.normal(kd[0], (B, 2, 16, 32))
+wq, wk, wv = (jax.random.normal(k, (32, 4, 8)) / 6 for k in kd[1:4])
+wo = jax.random.normal(kd[4], (4, 8, 32)) / 6
 kw = dict(use_pallas=True, interpret=True)
 
-def calls(x, R, dX, dF, mask, guard, gamma):
+def calls(x, R, dX, dF, mask, guard, gamma, h):
     G, u = ops.taa_gram(dF, R, mask, **kw)
     out = ops.taa_apply(x, R, dX, dF, gamma, mask, **kw)
     fused = ops.taa_round(x, R, dX, dF, mask, mode="taa", lam=1e-6,
                           safeguard_mask=guard, **kw)
-    return G, u, out, fused
+    h = window_constrain(h, "time")       # ParaTAA's window-row pin
+    return G, u, out, fused, ops.dit_attention(h, wq, wk, wv, wo, **kw)
 
-args = (x, R, dX, dF, mask, guard, gamma)
+args = (x, R, dX, dF, mask, guard, gamma, h)
 ref = jax.jit(jax.vmap(calls))(*args)
 out = {}
 for name, mesh in [("data4_model2", make_mesh("debug", data_parallel=4,
@@ -322,11 +328,16 @@ for name, mesh in [("data4_model2", make_mesh("debug", data_parallel=4,
                    ("data2_time2_model2", make_mesh("debug-time"))]:
     with serving_mesh(mesh):
         got = jax.jit(jax.vmap(calls, spmd_axis_name="data"))(*args)
+        kernel_spec = [str(a) for a in ops._dit_spec((4, 2, 8, 16))]
     out[name] = {
         "equal": all(np.array_equal(np.asarray(g), np.asarray(r))
-                     for g, r in zip(got, ref)),
+                     for g, r in zip(got[:4], ref[:4])),
+        # wo contracts the `model`-sharded heads: a psum of partial sums
+        "dit_err": float(np.max(np.abs(np.asarray(got[4] - ref[4])))),
         "devices": len(got[3].sharding.device_set),
-        "spec": [str(a) for a in got[3].sharding.spec]}
+        "spec": [str(a) for a in got[3].sharding.spec],
+        "kernel_spec": kernel_spec,
+        "dit_spec": [str(a) for a in got[4].sharding.spec] + ["None"] * 4}
 print("RESULT " + json.dumps(out))
 """
 
@@ -335,7 +346,10 @@ print("RESULT " + json.dumps(out))
 def test_pallas_kernels_run_per_device_under_serving_mesh():
     """GSPMD cannot partition a Mosaic kernel, so under a serving mesh the
     ops run each kernel per device (shard_map): the request axis stays
-    sharded over `data` and the values equal the meshless calls."""
+    sharded over `data` and the values equal the meshless calls.  The DiT
+    attention kernel takes its heads over `model` and, on a `time` mesh,
+    its window rows over `time` as ParaTAA pins them, so no time shard
+    attends rows it does not own."""
     proc = subprocess.run(
         [sys.executable, "-c", KERNEL_SCRIPT], capture_output=True,
         text=True,
@@ -349,6 +363,10 @@ def test_pallas_kernels_run_per_device_under_serving_mesh():
         assert rec["equal"], name
         assert rec["devices"] == 8, name
         assert rec["spec"][0] == "data", name
+        assert rec["dit_err"] < 1e-5, name
+        rows = "time" if "time" in name else "None"
+        assert rec["kernel_spec"] == ["model", rows, "None", "None"], name
+        assert rec["dit_spec"][:2] == ["data", rows], name
 
 
 # --- dry-run parataa cell measures the engine's sharded program -------------

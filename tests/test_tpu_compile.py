@@ -1,12 +1,13 @@
-"""Compile the main path's TAA kernels for a described TPU v5e chip.
+"""Compile the main path's kernels for a described TPU v5e chip.
 
 The Pallas TPU compiler is installed with JAX and compiles for a chip that
 is described rather than attached, so these tests catch what interpret
 mode cannot (block shapes off the (8, 128) tiling, unsupported in-kernel
-ops, VMEM overruns) without a chip.  Shapes are DiT-XL/2 at 256x256: T=25
+ops, VMEM overruns) without a chip.  TAA shapes are DiT-XL/2 at 256x256: T=25
 rows of D = 256 tokens x 16 = 4096, history m=3, vmapped over 4 request
-slots the way the sampling engine calls them.  Nothing runs; each test
-only compiles (about a second each).
+slots the way the sampling engine calls them; the DiT attention kernel
+runs at both benchmark cells' shapes.  Nothing runs; each test only
+compiles (about a second a kernel, ten for a step program).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every pytest worker
@@ -19,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import taa_update
+from repro.kernels import flash_attention, taa_update
 
 SLOTS, M, T, D = 4, 3, 25, 256 * 16
 
@@ -69,23 +70,20 @@ def test_taa_round_compiles_for_v5e(one_chip, mode):
              (SLOTS, M, T, D), (SLOTS, T), (SLOTS, T))
 
 
-def test_step_program_names_the_taa_kernels(one_chip):
-    """In the engine's stepwise step program the kernels' instructions are
-    named after them (``%_taa_gram.N``, ``%_taa_apply.N``), which is what a
-    device trace shows and what the benchmark's kernel share matches."""
-    from repro.configs.registry import ARCHS
+def _step_program_kernels(one_chip, cfg, solver):
+    """(instruction name, op_name) of every Pallas call in the engine's
+    stepwise step program, compiled for the described chip."""
     from repro.core import ddim_coeffs
     from repro.diffusion import dit
     from repro.launch import serve
     from repro.models.pdefs import is_def
     from repro.sampling import get_sampler
-    cfg = ARCHS["dit-xl"].reduced()
     params = jax.tree.map(
         lambda d: jax.ShapeDtypeStruct(d.shape, jnp.float32,
                                        sharding=one_chip),
         dit.dit_defs(cfg), is_leaf=is_def)
     engine = serve.make_engine(params, cfg, ddim_coeffs(8),
-                               get_sampler("taa", use_pallas=True))
+                               get_sampler(solver, use_pallas=True))
     xi = jax.ShapeDtypeStruct((9,) + tuple(engine.sample_shape),
                               jnp.float32)
     state = jax.tree.map(
@@ -94,9 +92,56 @@ def test_step_program_names_the_taa_kernels(one_chip):
     labels = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
     text = engine._stepwise_program("step", 1).lower(
         params, state, labels).compile().as_text()
-    kernels = re.findall(r"(%\S+) = \S+ custom-call\([^)]*\), "
-                         r'custom_call_target="tpu_custom_call"', text)
-    assert kernels and all(k.startswith("%_taa_") for k in kernels)
-    assert {re.sub(r"\.\d+$", "", k) for k in kernels} == \
-        {"%_taa_gram", "%_taa_apply"}
+    return re.findall(r"(%\S+) = \S+ custom-call\([^)]*\), "
+                      r'custom_call_target="tpu_custom_call".*?'
+                      r'op_name="([^"]*)"', text)
 
+
+def _names(kernels):
+    return {re.sub(r"\.\d+$", "", k) for k, _ in kernels}
+
+
+def test_step_program_names_the_taa_kernels(one_chip):
+    """In the engine's stepwise step program the kernels' instructions are
+    named after them (``%_taa_gram.N``, ``%_taa_apply.N``), which is what a
+    device trace shows and what the benchmark's kernel share matches."""
+    from repro.configs.registry import ARCHS
+    kernels = _step_program_kernels(one_chip, ARCHS["dit-xl"].reduced(),
+                                    "taa")
+    assert kernels and all(k.startswith("%_taa_") for k, _ in kernels)
+    assert _names(kernels) == {"%_taa_gram", "%_taa_apply"}
+
+
+@pytest.mark.parametrize("rows,n", [(25, 256), (1, 1024)],
+                         ids=["taa-256", "seq-1024"])
+def test_dit_flash_compiles_for_v5e(one_chip, rows, n):
+    """The DiT attention kernel at the benchmark cells' shapes: 8 slots of
+    16 heads of 72 for a 25-row window at 256 tokens, and for one row at
+    1024 tokens (q, k, v as (D, N))."""
+    qkv = (SLOTS * 2, 16, rows, 72, n)
+    _compile(flash_attention.dit_flash_attention, one_chip, "_dit_flash",
+             qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("solver", ["taa", "seq"])
+def test_step_program_runs_dit_attention_in_the_kernel(one_chip, solver,
+                                                       monkeypatch):
+    """On a TPU every DiT layer's attention is one ``%_dit_flash.N`` call
+    under the ``dit/attn`` scope (what ``attn_share`` reads), and the only
+    ``%_taa_`` calls are the solver's.  Full depth, smoke widths."""
+    import dataclasses
+    import sys
+    from pathlib import Path
+    from repro.configs.registry import ARCHS
+    from repro.kernels import ops
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "bench"))
+    from scopes import scope_path
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    layers = ARCHS["dit-xl"].num_layers
+    cfg = dataclasses.replace(ARCHS["dit-xl"].reduced(), num_layers=layers)
+    kernels = _step_program_kernels(one_chip, cfg, solver)
+    flash = [op for k, op in kernels if k.startswith("%_dit_flash.")]
+    assert len(flash) == layers
+    assert all(scope_path(op).endswith("dit/attn") for op in flash)
+    taa = {"taa": {"%_taa_gram", "%_taa_apply"}, "seq": set()}[solver]
+    assert _names(kernels) == {"%_dit_flash"} | taa
