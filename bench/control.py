@@ -29,7 +29,7 @@ import harness  # noqa: E402
 def read(cell, seed: int, seconds: float, meter, reading: str):
     """One short window; prints the compared numbers of what it served."""
     t0 = time.monotonic()
-    system = harness.build_system(cell.config, cell.traffic, seed)
+    system = harness.build_system(cell, seed)
     harness.warm_up(system)
     window = harness.run_window(system, seed, seconds, meter=meter)
     attempted, failed = harness.attempted_failed(system, window)

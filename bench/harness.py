@@ -3,11 +3,34 @@ files, warms it up, drives one measured window of traffic through the
 program's serving loop, reduces what it saw to metrics, and checks what the
 timed path served against the plain reference.
 
-The program is driven only through its public serving API:
-``repro.launch.serve.make_engine`` behind an ``EngineRegistry``, a
-``RequestQueue``, a ``Batcher`` and a stepwise ``ServingLoop``.  The load
-generator, the timing, the reduction and the reference are the
-benchmark's own.
+The program is driven only through its public serving API: the model's
+engine behind an ``EngineRegistry``, a ``RequestQueue``, a ``Batcher`` and
+a stepwise ``ServingLoop``.  The load generator, the timing, the reduction
+and the reference are the benchmark's own.
+
+Everything that depends on the model lives in one module per architecture,
+``models/<arch>.py`` under the cell's benchmark directory, chosen by the
+configuration file's ``arch`` key and loaded by path.  It provides:
+
+    program_arch(cfg)       the program's architecture object, from the
+                            configuration's sizes
+    program_layout(arch)    the weight shapes the program reads, a tree of
+                            shape tuples (``check_layout`` compares it)
+    leaf_shapes(cfg)        the weight tree ``weights.make_weights`` fills
+    make_engine(params, arch, coeffs, spec, placement)
+                            the program's ``SamplingEngine`` for the model
+    draw_condition(rng, cfg)  a request's condition, a small JSON-able
+                            value drawn from the run's generator
+    warm_condition(i, cfg)  the condition of the i-th warm-up request
+    request_kwargs(cond)    ``SampleRequest`` keyword arguments for it
+    forward(params, x, t, cond, *, dtype)
+                            the plain reference denoiser: eps for (R, ...)
+                            rows at (R,) timesteps under one condition
+    forward_flops(cfg)      FLOPs of one served row's evaluation
+
+So a configuration of another denoiser is added as new files: its
+configuration, its model module, its traffic and limits files and its
+metric readers.
 """
 from __future__ import annotations
 
@@ -45,6 +68,7 @@ class Cell:
     limits: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    bench: Path                      # the benchmark directory it came from
 
 
 def _read_json(path: Path) -> dict:
@@ -71,16 +95,34 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                 config=_read_json(root / conf["file"]),
                 traffic=_read_json(bench / "traffic" / f"{wl['traffic']}.json"),
                 limits=_read_json(bench / "limits" / f"{name}.json"),
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer, bench=bench)
 
 
-def metric_reader(name: str):
-    """``metrics/<name>.py``'s ``read(ctx)``."""
-    path = BENCH_DIR / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def metric_reader(name: str, bench: Path = BENCH_DIR):
+    """``metrics/<name>.py``'s ``read(ctx)``."""
+    return _load(bench / "metrics" / f"{name}.py", f"metric_{name}").read
+
+
+_MODELS: Dict[Path, object] = {}
+
+
+def load_model(arch: str, bench: Path = BENCH_DIR):
+    """The model module ``models/<arch>.py``, loaded once per process (its
+    ``forward`` is a static argument of the reference's jitted step, so it
+    keeps one identity)."""
+    path = (bench / "models" / f"{arch}.py").resolve()
+    if path not in _MODELS:
+        if not path.is_file():
+            raise SystemExit(f"no model module for arch {arch!r} at {path}")
+        _MODELS[path] = _load(path, f"model_{arch}")
+    return _MODELS[path]
 
 
 def peaks_for(kind: str) -> dict:
@@ -152,25 +194,10 @@ class CompileMeter:
 # ---------------------------------------------------------------------------
 
 
-SIZE_KEYS = ("num_layers", "d_model", "num_heads", "head_dim", "d_ff",
-             "latent_dim", "num_tokens", "num_classes")
-
-
-def program_arch(cfg: dict):
-    """The program's ArchConfig for this configuration: its registered
-    architecture with every size taken from the configuration file."""
-    from repro.configs.registry import get_arch
-    sizes = {k: cfg[k] for k in SIZE_KEYS}
-    return dataclasses.replace(get_arch(cfg["arch"]), **sizes)
-
-
-def check_layout(params, arch) -> None:
-    """The benchmark's weight tree has the shapes the program's DiT reads."""
+def check_layout(params, model, arch) -> None:
+    """The benchmark's weight tree has the shapes the program reads."""
     import jax
-    from repro.diffusion import dit
-    from repro.models.pdefs import is_def
-    want = jax.tree.map(lambda d: tuple(d.shape), dit.dit_defs(arch),
-                        is_leaf=is_def)
+    want = model.program_layout(arch)
     have = jax.tree.map(lambda a: tuple(a.shape), params)
     if want != have:
         raise SystemExit(f"weight layout differs from the program's: "
@@ -182,6 +209,7 @@ class System:
     """Weights plus one warmed engine of the program."""
     cfg: dict
     traffic: dict
+    model: object                    # the configuration's model module
     params: object
     engine: object
     key: object
@@ -195,28 +223,48 @@ def sampler_spec(traffic: dict):
     return get_sampler(solver.pop("name"), **solver)
 
 
-def build_system(cfg: dict, traffic: dict, seed: int) -> System:
+def placement_for(traffic: dict, chips: int):
+    """The engine's placement: the traffic file's optional ``mesh`` block
+    (``{"name": <registered mesh>, "data": n, "time": n, "model": n}``, a
+    size left out keeping the mesh's own) through ``serve.make_placement``,
+    spanning exactly the cell's chips; without one, the host placement."""
+    from repro.launch import serve
+    mesh = traffic.get("mesh")
+    if mesh is None:
+        return serve.make_placement("none")
+    placement = serve.make_placement(
+        mesh["name"], data_parallel=mesh.get("data", 0),
+        model_parallel=mesh.get("model", 0),
+        time_parallel=mesh.get("time", 0))
+    if placement.mesh.devices.size != chips:
+        raise SystemExit(f"mesh {mesh} spans {placement.mesh.devices.size} "
+                         f"devices; the cell asks for {chips} chip(s)")
+    return placement
+
+
+def build_system(cell: Cell, seed: int) -> System:
     import jax
     from repro.core import ddim_coeffs
-    from repro.launch import serve
-    from repro.sampling import Placement
     from repro.serving import EngineKey
     import weights
 
+    cfg, traffic = cell.config, cell.traffic
     if traffic["sampler"] != "ddim":
         raise SystemExit(f"sampler {traffic['sampler']!r}: the reference "
                          f"implements DDIM only")
-    arch = program_arch(cfg)
-    params = weights.make_weights(cfg, weights.seed_key(seed))
+    model = load_model(cfg["arch"], cell.bench)
+    arch = model.program_arch(cfg)
+    params = weights.make_weights(model.leaf_shapes(cfg), cfg["init"],
+                                  weights.seed_key(seed))
     jax.block_until_ready(params)
-    check_layout(params, arch)
-    engine = serve.make_engine(params, arch, ddim_coeffs(traffic["T"]),
+    check_layout(params, model, arch)
+    engine = model.make_engine(params, arch, ddim_coeffs(traffic["T"]),
                                sampler_spec(traffic),
-                               placement=Placement.host())
+                               placement_for(traffic, cell.chips))
     key = EngineKey(cfg["name"], traffic["T"], traffic["solver"]["name"])
     slots = engine.placement.round_batch(traffic["slots"])
-    return System(cfg=cfg, traffic=traffic, params=params, engine=engine,
-                  key=key, slots=slots,
+    return System(cfg=cfg, traffic=traffic, model=model, params=params,
+                  engine=engine, key=key, slots=slots,
                   sample_shape=tuple(engine.sample_shape))
 
 
@@ -228,20 +276,25 @@ def warm_up(system: System) -> None:
     engine, slots = system.engine, system.slots
     chunk = system.traffic["chunk_iters"]
     seq = engine.spec.is_sequential
+
+    def cond(i):
+        return system.model.request_kwargs(
+            system.model.warm_condition(i, system.cfg))
+
     for k in range(1, slots + 1):
         bank = engine.stepwise_open(slots, chunk_iters=chunk)
         # a ParaTAA lane with max_iters=0 retires at birth, so the harvest
         # of k lanes at once costs no step
-        reqs = [SampleRequest(label=i, seed=i) if seq else
-                SampleRequest(label=i, seed=i, max_iters=0)
+        reqs = [SampleRequest(seed=i, **cond(i)) if seq else
+                SampleRequest(seed=i, max_iters=0, **cond(i))
                 for i in range(k)]
         engine.stepwise_refill(bank, list(range(k)), reqs)
         if not seq:
             engine.stepwise_harvest(bank)
     # one lane through the steady round: step, piggybacked poll, harvest
     bank = engine.stepwise_open(slots, chunk_iters=chunk)
-    req = SampleRequest(label=1, seed=1) if seq else \
-        SampleRequest(label=1, seed=1, quality_steps=chunk)
+    req = SampleRequest(seed=1, **cond(1)) if seq else \
+        SampleRequest(seed=1, quality_steps=chunk, **cond(1))
     engine.stepwise_refill(bank, [0], [req])
     for _ in range(2 * system.traffic["T"] + 2):    # a broken step never
         engine.stepwise_step(bank)                  # retires the lane
@@ -333,22 +386,25 @@ def run_window(system: System, seed: int, seconds: float, *,
         metrics=obs.metrics)
     loop = marked_loop(registry, queue, batcher,
                        chunk_iters=traffic["chunk_iters"], obs=obs)
-    key = system.key
-    classes = system.cfg["num_classes"]
+    key, model = system.key, system.model
 
-    def submit(label, noise_seed, due):
-        return queue.submit(SampleRequest(label=label, seed=noise_seed,
-                                          arrival_time=due), key)
+    def draw_condition(rng):
+        return model.draw_condition(rng, system.cfg)
+
+    def submit(cond, noise_seed, due):
+        return queue.submit(SampleRequest(seed=noise_seed, arrival_time=due,
+                                          **model.request_kwargs(cond)), key)
 
     drain_s = float(traffic["drain_s"])
     if traffic["arrivals"] == "poisson":
         gen = loadgen.OpenLoop(loadgen.poisson_schedule(
             traffic["arrival_seed"], seed,
-            rate_per_s or traffic["rate_per_s"], seconds, classes),
+            rate_per_s or traffic["rate_per_s"], seconds, draw_condition),
             submit, clock=queue.clock)
     elif traffic["arrivals"] == "closed":
         gen = loadgen.ClosedLoop(seed, traffic["clients"],
-                                 traffic["client_stagger_s"], classes,
+                                 traffic["client_stagger_s"],
+                                 draw_condition,
                                  submit, result_timeout=seconds + drain_s,
                                  clock=queue.clock)
     else:
@@ -443,7 +499,6 @@ def attempted_failed(system: System, window: Window) -> tuple:
 def layer_context(system: System, window: Window, chips: int,
                   peaks: dict) -> dict:
     """What the per-layer readers read."""
-    import counts
     served = outcome(window.sent)
     in_window = [res for s, res in served if res is not None
                  and window.t0 <= s.ticket.completed_time <= window.t1]
@@ -458,7 +513,7 @@ def layer_context(system: System, window: Window, chips: int,
         "chunk_iters": system.traffic["chunk_iters"],
         "slots": system.slots,
         "rows_per_lane_iter": system.engine.window,
-        "flops_per_row": counts.dit_forward_flops(system.cfg),
+        "flops_per_row": system.model.forward_flops(system.cfg),
         "completed_iters": [int(r.iters) for r in in_window],
         "bank_reports": window.bank_reports,
         "sequential": spec.is_sequential,
@@ -518,7 +573,8 @@ def correctness(system: System, window: Window, seed: int,
                                           shape, jnp.float32))
         noise = max(noise, float(np.max(np.abs(traj[T] - xi[T]))))
         g2, b2 = reference.step_readings(
-            system.params, traj, s.label, sched, block=check["block"],
+            system.model.forward, system.params, traj, s.cond, sched,
+            block=check["block"],
             dtype=jnp.float32 if dtype is None else dtype)
         gap = max(gap, reference.step_gap(g2, b2, thresh2))
     _, failed = attempted_failed(system, window)
@@ -554,7 +610,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     dev = devices[0]
     peaks = peaks_for(dev.device_kind) if dev.platform == "tpu" else None
     meter = CompileMeter()
-    system = build_system(cell.config, cell.traffic, seed)
+    system = build_system(cell, seed)
     warm_up(system)
     out(f"set-up: {meter.counts['compiles']} backend compile(s) "
         f"({meter.compile_s:.1f}s), {meter.counts['cache_hits']} "
@@ -583,7 +639,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         ctx = layer_context(system, window, cell.chips, peaks)
         metrics = {}
         for m in cell.per_layer:
-            value = metric_reader(m["name"])(ctx)
+            value = metric_reader(m["name"], cell.bench)(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     else:

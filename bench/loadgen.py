@@ -6,8 +6,8 @@ file's ``arrival_seed`` fixes the number of requests, round(rate *
 seconds), and their due times, drawn uniform over the window and sorted,
 which is a Poisson process conditioned on its count; the run's seed draws
 the requests themselves.  Every seed thus offers the same arrivals, with
-other labels and noises, so the tail does not move with the clustering a
-seed would draw.  Each
+other conditions and noises, so the tail does not move with the clustering
+a seed would draw.  Each
 request is submitted at its due time on the queue's clock and carries that
 due time as its ``arrival_time``, so a late generator or a stalled server
 shows in the latency of every later request, and the generator's own
@@ -18,15 +18,15 @@ its next request the moment its last one returns, the first ones spaced
 ``client_stagger_s`` apart.  A client's requests are drawn from the seed
 and the client's index.
 
-Labels are uniform over the configuration's classes; each request's noise
-seed is drawn from the seed too.
+Each request's condition is drawn from the seed by ``draw_condition(rng)``
+(the model module's, ``models/<arch>.py``), then its noise seed.
 """
 from __future__ import annotations
 
 import dataclasses
 import threading
 import time
-from typing import Callable, List, Optional
+from typing import Any, Callable, List, Optional
 
 import numpy as np
 
@@ -34,26 +34,28 @@ import numpy as np
 @dataclasses.dataclass
 class Sent:
     """One request as the generator sent it."""
-    label: int
+    cond: Any                       # the model module's condition
     noise_seed: int
     due: float                      # queue clock
     submitted: Optional[float] = None
     ticket: object = None
 
 
-def draw_request(rng: np.random.Generator, num_classes: int):
-    return int(rng.integers(num_classes)), int(rng.integers(1 << 30))
+def draw_request(rng: np.random.Generator, draw_condition: Callable):
+    """(condition, noise seed) of the next request."""
+    return draw_condition(rng), int(rng.integers(1 << 30))
 
 
 def poisson_schedule(arrival_seed: int, seed: int, rate_per_s: float,
-                     seconds: float, num_classes: int) -> List[Sent]:
+                     seconds: float, draw_condition: Callable) -> List[Sent]:
     """Due times (seconds after the window opens), drawn from
     ``arrival_seed``, and requests, drawn from ``seed``."""
     n = int(round(rate_per_s * seconds))
     due = np.sort(np.random.default_rng([int(arrival_seed), 1])
                   .uniform(0.0, seconds, n))
     rng = np.random.default_rng([int(seed), 1])
-    return [Sent(*draw_request(rng, num_classes), due=float(t)) for t in due]
+    return [Sent(*draw_request(rng, draw_condition), due=float(t))
+            for t in due]
 
 
 class OpenLoop:
@@ -79,7 +81,7 @@ class OpenLoop:
             if wait > 0:
                 time.sleep(wait)
             s.submitted = self._clock()
-            s.ticket = self._submit(s.label, s.noise_seed, s.due)
+            s.ticket = self._submit(s.cond, s.noise_seed, s.due)
 
     def stop(self, timeout: float) -> None:
         """Every request of the schedule is due inside the window, so the
@@ -95,14 +97,15 @@ class ClosedLoop:
     """``clients`` threads, each with its own request stream."""
 
     def __init__(self, seed: int, clients: int, stagger_s: float,
-                 num_classes: int, submit: Callable, result_timeout: float,
+                 draw_condition: Callable, submit: Callable,
+                 result_timeout: float,
                  clock: Callable[[], float] = time.monotonic):
         self.sent: List[Sent] = []
         self._lock = threading.Lock()
         self._seed = seed
         self._clients = clients
         self._stagger = stagger_s
-        self._classes = num_classes
+        self._draw_condition = draw_condition
         self._submit = submit
         self._timeout = result_timeout
         self._clock = clock
@@ -124,10 +127,10 @@ class ClosedLoop:
         if wait > 0 and self._stop.wait(wait):
             return
         while not self._stop.is_set():
-            label, noise = draw_request(rng, self._classes)
+            cond, noise = draw_request(rng, self._draw_condition)
             now = self._clock()
-            s = Sent(label, noise, due=now, submitted=now)
-            s.ticket = self._submit(label, noise, now)
+            s = Sent(cond, noise, due=now, submitted=now)
+            s.ticket = self._submit(cond, noise, now)
             with self._lock:
                 self.sent.append(s)
             try:

@@ -1,15 +1,8 @@
-"""Plain reference: the DiT denoiser (Peebles & Xie 2023) and its DDIM step.
+"""Plain reference: the DDIM step around a model's reference denoiser.
 
-Written from the DiT paper in straightforward ``jax.numpy`` and imports
-nothing of the program.  It follows the architecture the program serves,
-which departs from the paper in four places (each a key the
-configuration lists in ``reduced``): a 1-D sin-cos position table over the N
-tokens instead of the 2-D one, a gated GELU MLP (GeGLU, two input
-matrices) instead of the plain one, no biases, and an eps-only output (no
-learned-sigma channels).
-
-The forward scans over the stacked layers, so that it compiles in seconds
-at full depth, and computes in float32 with ``precision="highest"`` unless
+Written in straightforward ``jax.numpy`` and imports nothing of the
+program.  The denoiser is the model module's ``forward`` (``models/
+<arch>.py``), which computes in float32 with ``precision="highest"`` unless
 a lower ``dtype`` is asked for (the control: everything in that dtype).
 
 DDIM (eta = 0, Song et al. 2020) on DDPM's linear beta schedule, with the
@@ -26,10 +19,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-TEMB_DIM = 256
-LN_EPS = 1e-6
-
 
 def ddim_schedule(T: int, n_train: int = 1000, beta_min: float = 1e-4,
                   beta_max: float = 0.02) -> dict:
@@ -51,99 +40,31 @@ def ddim_schedule(T: int, n_train: int = 1000, beta_min: float = 1e-4,
     return {"a": a, "b": b, "tau": tau, "g2": g2}
 
 
-def _layernorm(x):
-    mu = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
-    return (x - mu) / jnp.sqrt(var + LN_EPS)
-
-
-def _modulate(x, shift, scale):
-    return x * (1.0 + scale[:, None, :]) + shift[:, None, :]
-
-
-def _timestep_embedding(t, dim: int = TEMB_DIM, max_period: float = 1e4):
-    """DiT's TimestepEmbedder frequencies: [cos, sin] of t * f_i."""
-    half = dim // 2
-    freqs = jnp.exp(-np.log(max_period) * jnp.arange(half) / half)
-    args = t[:, None] * freqs[None, :]
-    return jnp.concatenate([jnp.cos(args), jnp.sin(args)], axis=-1)
-
-
-def _positions(n: int, d: int):
-    """1-D sin-cos table (n, d): [sin, cos] of position * f_i."""
-    half = d // 2
-    freqs = np.exp(-np.log(10_000.0) * np.arange(half) / half)
-    ang = np.arange(n)[:, None] * freqs[None, :]
-    return np.concatenate([np.sin(ang), np.cos(ang)], axis=-1)
-
-
-def _gelu_tanh(x):
-    return 0.5 * x * (1.0 + jnp.tanh(float(np.sqrt(2.0 / np.pi))
-                                     * (x + 0.044715 * x ** 3)))
-
-
-def forward(params, x, t, y, *, dtype=jnp.float32):
-    """eps for a block of rows.  x: (R, N, L_in); t: (R,) float timesteps;
-    y: (R,) int labels.  Returns float32 (R, N, L_in)."""
-    prec = "highest" if dtype == jnp.float32 else "default"
-    mm = functools.partial(jnp.einsum, precision=prec)
-    p = jax.tree.map(lambda w: w.astype(dtype), params)
-    x = x.astype(dtype)
-    n, d = x.shape[1], p["in_proj"].shape[1]
-    h = mm("rnl,ld->rnd", x, p["in_proj"]) \
-        + jnp.asarray(_positions(n, d), dtype)[None]
-    temb = _timestep_embedding(t.astype(jnp.float32)).astype(dtype)
-    c = mm("re,ed->rd", jax.nn.silu(mm("rf,fe->re", temb, p["t_mlp1"])),
-           p["t_mlp2"])
-    c = jax.nn.silu(c + p["y_embed"][y])
-    scale = np.asarray(1.0 / np.sqrt(p["blocks"]["wq"].shape[-1]), dtype)
-
-    def layer(h, w):
-        s1, sc1, g1, s2, sc2, g2 = jnp.split(mm("rd,de->re", c, w["ada"]),
-                                             6, axis=-1)
-        u = _modulate(_layernorm(h), s1, sc1)
-        q = mm("rnd,dhk->rnhk", u, w["wq"])
-        k = mm("rnd,dhk->rnhk", u, w["wk"])
-        v = mm("rnd,dhk->rnhk", u, w["wv"])
-        att = jax.nn.softmax(mm("rnhk,rmhk->rhnm", q, k) * scale, axis=-1)
-        o = mm("rhnm,rmhk->rnhk", att, v)
-        h = h + g1[:, None, :] * mm("rnhk,hkd->rnd", o, w["wo"])
-        u = _modulate(_layernorm(h), s2, sc2)
-        m = _gelu_tanh(mm("rnd,df->rnf", u, w["mlp"]["wi_gate"])) \
-            * mm("rnd,df->rnf", u, w["mlp"]["wi_up"])
-        h = h + g2[:, None, :] * mm("rnf,fd->rnd", m, w["mlp"]["wo"])
-        return h, None
-
-    h, _ = jax.lax.scan(layer, h, p["blocks"])
-    shift, sc = jnp.split(mm("rd,de->re", c, p["final_ada"]), 2, axis=-1)
-    out = mm("rnd,dl->rnl", _modulate(_layernorm(h), shift, sc),
-             p["out_proj"])
-    return out.astype(jnp.float32)
-
-
-@functools.partial(jax.jit, static_argnames=("dtype",))
-def _step_block(params, x_t, x_prev, t, y, a, b, *, dtype):
+@functools.partial(jax.jit, static_argnames=("forward", "dtype"))
+def _step_block(forward, params, x_t, x_prev, t, cond, a, b, *, dtype):
     """Per-row teacher-forced DDIM step over a block of rows: the squared
     gap |x_prev - (a x_t + b eps(x_t))|^2 against the float32 reference,
     and |b eps|^2.  With ``dtype`` below float32, ``x_prev`` is ignored and
     replaced by the whole step, denoiser and recursion, computed in that
     precision (the control)."""
-    eps = forward(params, x_t, t, y)
+    eps = forward(params, x_t, t, cond)
     be = b[:, None, None] * eps
     if dtype != jnp.float32:
         low = [v.astype(dtype) for v in (a[:, None, None], x_t,
                                          b[:, None, None])]
         x_prev = (low[0] * low[1] + low[2] * forward(
-            params, x_t, t, y, dtype=dtype).astype(dtype)
+            params, x_t, t, cond, dtype=dtype).astype(dtype)
                   ).astype(jnp.float32)
     gap = x_prev - (a[:, None, None] * x_t + be)
     return jnp.sum(gap ** 2, axis=(1, 2)), jnp.sum(be ** 2, axis=(1, 2))
 
 
-def step_readings(params, trajectory, label: int, sched: dict, *,
+def step_readings(forward, params, trajectory, cond, sched: dict, *,
                   block: int, dtype=jnp.float32):
     """Teacher-forced readings of one served trajectory (T+1, N, L_in),
-    rows in index order (row T is the initial noise, row 0 is x0).
+    rows in index order (row T is the initial noise, row 0 is x0), under
+    the reference denoiser ``forward`` (a model module's) and the
+    request's condition ``cond``.
 
     For each t = 1..T the reference takes the served x_t, computes its own
     DDIM step, and returns ``(gap2, beps2)``: (T,) squared L2 norms of the
@@ -153,6 +74,7 @@ def step_readings(params, trajectory, label: int, sched: dict, *,
     recursion alike, puts in the same served x_t's place."""
     X = jnp.asarray(trajectory, jnp.float32)
     T = X.shape[0] - 1
+    cond = jax.tree.map(jnp.asarray, cond)
     gap2, beps2 = [], []
     for lo in range(1, T + 1, block):
         ts = np.arange(lo, min(lo + block, T + 1))
@@ -160,9 +82,8 @@ def step_readings(params, trajectory, label: int, sched: dict, *,
         a = jnp.asarray(sched["a"][ts_p], jnp.float32)
         b = jnp.asarray(sched["b"][ts_p], jnp.float32)
         tau = jnp.asarray(sched["tau"][ts_p], jnp.float32)
-        y = jnp.full((block,), label, jnp.int32)
-        g, e = _step_block(params, X[ts_p], X[ts_p - 1], tau, y, a, b,
-                           dtype=dtype)
+        g, e = _step_block(forward, params, X[ts_p], X[ts_p - 1], tau, cond,
+                           a, b, dtype=dtype)
         gap2.append(np.asarray(g)[:len(ts)])
         beps2.append(np.asarray(e)[:len(ts)])
     return np.concatenate(gap2), np.concatenate(beps2)

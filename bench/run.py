@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Chip benchmark of the DiT serving path: one cell, one measured window.
+"""Chip benchmark of the diffusion serving path: one cell, one measured window.
 
     python bench/run.py --workload xl256-taa-poisson --seed 7 \\
         --seconds 51 --trace 0

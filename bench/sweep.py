@@ -38,7 +38,7 @@ def main(argv=None) -> int:
     harness.configure_jax()
     harness.find_devices(cell.chips)
     meter = harness.CompileMeter()
-    system = harness.build_system(cell.config, cell.traffic, args.seed)
+    system = harness.build_system(cell, args.seed)
     harness.warm_up(system)
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
         w = harness.run_window(system, args.seed + i, args.seconds,

@@ -90,7 +90,7 @@ def test_control_fails_the_limit(name):
     import jax
     import jax.numpy as jnp
     cell = small_cell(name)
-    system = harness.build_system(cell.config, cell.traffic, SEED)
+    system = harness.build_system(cell, SEED)
     harness.warm_up(system)
     window = harness.run_window(system, SEED, 1.5)
     system.engine = None

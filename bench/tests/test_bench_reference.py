@@ -1,5 +1,8 @@
 """The plain reference against the program at a reduced size on the CPU,
-and the shape arithmetic the per-layer metrics divide by."""
+the seeded weights, and the shape arithmetic the per-layer metrics divide
+by."""
+import hashlib
+import json
 import os
 import sys
 
@@ -9,8 +12,11 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
 
 import counts  # noqa: E402
+import harness  # noqa: E402
 import reference  # noqa: E402
 import weights  # noqa: E402
+
+DIT = harness.load_model("dit-xl")
 
 # the full configurations' shape keys, at a size a CPU test holds
 SMALL = dict(num_layers=2, d_model=64, num_heads=4, head_dim=16, d_ff=128,
@@ -18,20 +24,62 @@ SMALL = dict(num_layers=2, d_model=64, num_heads=4, head_dim=16, d_ff=128,
 BENCH = os.path.join(os.path.dirname(__file__), os.pardir)
 
 
+def _config_file(name):
+    with open(os.path.join(BENCH, "configs", f"{name}.json")) as f:
+        return json.load(f)
+
+
 def _config(**sizes):
-    import json
-    with open(os.path.join(BENCH, "configs", "dit-xl2-256.json")) as f:
-        cfg = json.load(f)
-    cfg.update(sizes)
-    return cfg
+    return dict(_config_file("dit-xl2-256"), **sizes)
+
+
+def _weights(cfg):
+    return weights.make_weights(DIT.leaf_shapes(cfg), cfg["init"],
+                                weights.seed_key(2**31 + 11))
 
 
 @pytest.fixture(scope="module")
 def small():
-    import harness
     cfg = _config(**SMALL)
-    params = weights.make_weights(cfg, weights.seed_key(2**31 + 11))
-    return cfg, params, harness.program_arch(cfg)
+    return cfg, _weights(cfg), DIT.program_arch(cfg)
+
+
+#: sha256 (first 16 hex digits) of each leaf's float32 bytes at SMALL
+#: sizes and seed 2**31 + 11, as the benchmark made them before its
+#: model-specific code moved into ``models/dit-xl.py``
+LEAF_DIGESTS = {
+    "blocks.ada": "22a3dfd27947b53b",
+    "blocks.mlp.wi_gate": "3fea26a1895c6828",
+    "blocks.mlp.wi_up": "98f02c20a41b15f6",
+    "blocks.mlp.wo": "607c7f4d0aadb83c",
+    "blocks.wk": "d86f1316014586e0",
+    "blocks.wo": "ac6bde05c5c0edf7",
+    "blocks.wq": "11e5e658fb706b29",
+    "blocks.wv": "f2e7feeaa8312632",
+    "final_ada": "ea5b887838b8e20f",
+    "in_proj": "91412c69c0f8dbb4",
+    "out_proj": "3afda63c9194d54c",
+    "t_mlp1": "89a8196d9d89a9d5",
+    "t_mlp2": "63cd88e9312bcaa1",
+    "y_embed": "f364ffaa6e5d2b6f",
+}
+
+
+def test_weights_are_pinned(small):
+    """The same weights, bit for bit, as before the move."""
+    import jax
+    _, params, _ = small
+    got = {".".join(k.key for k in path):
+           hashlib.sha256(np.asarray(leaf).tobytes()).hexdigest()[:16]
+           for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]}
+    assert got == LEAF_DIGESTS
+
+
+def test_layout_matches_program(small):
+    """The module's weight tree is the program's layout (``dit_defs``)."""
+    cfg, params, arch = small
+    harness.check_layout(params, DIT, arch)
+    assert DIT.program_layout(arch) == DIT.leaf_shapes(cfg)
 
 
 def test_forward_matches_dit_apply(small):
@@ -44,7 +92,7 @@ def test_forward_matches_dit_apply(small):
     x = jax.random.normal(jax.random.PRNGKey(1), (3, 16, 16))
     t = jnp.asarray([39.0, 499.0, 999.0])
     y = jnp.asarray([0, 4, 9])
-    ref = np.asarray(reference.forward(params, x, t, y))
+    ref = np.asarray(DIT.forward(params, x, t, y))
     got = np.asarray(dit.dit_apply(params, arch, x, t, y))
     assert np.linalg.norm(got - ref) <= 1e-5 * np.linalg.norm(ref)
     # the calibrated init makes eps depend on x
@@ -59,6 +107,24 @@ def test_schedule_matches_program():
         np.testing.assert_allclose(mine["b"], theirs.b, rtol=1e-12)
         np.testing.assert_allclose(mine["tau"], theirs.taus, rtol=1e-12)
         np.testing.assert_allclose(mine["g2"], theirs.g2, rtol=1e-12)
+
+
+@pytest.mark.parametrize("dtype,gap,rtol", [
+    ("float32", 6.780295207556427, 1e-6),
+    ("bfloat16", 0.01727736194757697, 1e-2)])
+def test_step_readings_are_pinned(small, dtype, gap, rtol):
+    """The teacher-forced readings of one fixed (not served) trajectory
+    under label 3 give the step_gap they gave before the move: to float32
+    rounding, and the bfloat16 control to a bfloat16 rounding of a few
+    elements (the order of a sum may differ between CPU builds)."""
+    import jax
+    import jax.numpy as jnp
+    _, params, _ = small
+    traj = jax.random.normal(jax.random.PRNGKey(7), (9, 16, 16))
+    g2, b2 = reference.step_readings(DIT.forward, params, traj, 3,
+                                     reference.ddim_schedule(8), block=3,
+                                     dtype=getattr(jnp, dtype))
+    assert reference.step_gap(g2, b2) == pytest.approx(gap, rel=rtol)
 
 
 @pytest.mark.parametrize("solver", ["seq", "taa"])
@@ -82,16 +148,18 @@ def test_served_rows_match_reference(small, solver):
         reference.stopping_thresholds(sched, spec.tau, D)
     prog, ctl = 0.0, 0.0
     for res in results:
-        g2, b2 = reference.step_readings(params, res.trajectory,
-                                         res.request.label, sched, block=3)
+        g2, b2 = reference.step_readings(DIT.forward, params,
+                                         res.trajectory, res.request.label,
+                                         sched, block=3)
         if thresh2 is not None:
             # the program's own residuals obey the same stated tolerance
             assert res.converged
             assert np.all(res.residuals <= thresh2 * (1 + 1e-6))
         prog = max(prog, reference.step_gap(g2, b2, thresh2))
-        g2c, b2c = reference.step_readings(params, res.trajectory,
-                                           res.request.label, sched,
-                                           block=3, dtype=jnp.bfloat16)
+        g2c, b2c = reference.step_readings(DIT.forward, params,
+                                           res.trajectory, res.request.label,
+                                           sched, block=3,
+                                           dtype=jnp.bfloat16)
         ctl = max(ctl, reference.step_gap(g2c, b2c, thresh2))
     assert prog < 1e-4
     assert ctl > 100 * max(prog, 1e-4)
@@ -102,16 +170,21 @@ def test_dit_flops_match_the_paper():
     at 256x256 and 524.6 G at 512x512 (rounded, from its FLOP counter,
     hence the 0.1% tolerance)."""
     for name, gmac in (("dit-xl2-256", 118.6), ("dit-xl2-512", 524.6)):
-        import json
-        with open(os.path.join(BENCH, "configs", f"{name}.json")) as f:
-            cfg = json.load(f)
-        macs = counts.dit_forward_flops(cfg, paper=True) / 2
+        cfg = _config_file(name)
+        macs = DIT.forward_flops(cfg, paper=True) / 2
         assert macs / 1e9 == pytest.approx(gmac, rel=1e-3)
         # the program's gated MLP adds a third d x d_ff matrix per layer
         extra = 2 * cfg["num_layers"] * cfg["num_tokens"] \
             * cfg["d_model"] * cfg["d_ff"]
-        assert counts.dit_forward_flops(cfg) > \
-            counts.dit_forward_flops(cfg, paper=True) + 0.99 * extra
+        assert DIT.forward_flops(cfg) > \
+            DIT.forward_flops(cfg, paper=True) + 0.99 * extra
+
+
+@pytest.mark.parametrize("name,flops", [("dit-xl2-256", 313334857728),
+                                        ("dit-xl2-512", 1353444655104)])
+def test_dit_flops_are_pinned(name, flops):
+    """``flops_per_row`` of each cell, exactly as before the move."""
+    assert DIT.forward_flops(_config_file(name)) == flops
 
 
 @pytest.mark.parametrize("T,D,m", [(25, 4096, 3), (8, 200, 2)])

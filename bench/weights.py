@@ -1,51 +1,17 @@
-"""Seeded DiT weights, made on the device in one jitted call.
+"""Seeded weights, made on the device in one jitted call.
 
-The benchmark makes the weights itself, so that the plain reference
-(``reference.py``) and the system under test read the same arrays and
-neither takes anything the other made.  The tree has the layout the
-program's DiT reads (``blocks`` stacked over layers):
-
-    in_proj (L_in, d)   t_mlp1 (256, d)   t_mlp2 (d, d)   y_embed (C+1, d)
-    blocks: ada (L, d, 6d)  wq/wk/wv (L, d, H, hd)  wo (L, H, hd, d)
-            mlp: wi_gate/wi_up (L, d, ff)  wo (L, ff, d)
-    final_ada (d, 2d)   out_proj (d, L_in)
-
-Each leaf is normal with the std the configuration's ``init`` block gives.
-DiT initialises ``ada``, ``final_ada`` and ``out_proj`` to zero, which makes
-a random model's eps identically 0 and every solve trivial, so the
-configuration gives those leaves small seeded values instead (its
-``assumed`` list says why).
+The benchmark makes the weights itself, so that the plain reference and
+the system under test read the same arrays and neither takes anything the
+other made.  The model module (``models/<arch>.py``) gives the tree's
+shapes, ``leaf_shapes(cfg)``, in the layout the program reads; each leaf
+is normal with the std the configuration's ``init`` block gives, drawn
+from ``fold_in(key, i)`` for the i-th leaf in the tree's order.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-TEMB_DIM = 256
-
-
-def leaf_shapes(cfg: dict) -> dict:
-    d, h, hd = cfg["d_model"], cfg["num_heads"], cfg["head_dim"]
-    ff, n_layers = cfg["d_ff"], cfg["num_layers"]
-    lat, ncls = cfg["latent_dim"], cfg["num_classes"]
-    return {
-        "in_proj": (lat, d),
-        "t_mlp1": (TEMB_DIM, d),
-        "t_mlp2": (d, d),
-        "y_embed": (ncls + 1, d),
-        "blocks": {
-            "ada": (n_layers, d, 6 * d),
-            "wq": (n_layers, d, h, hd),
-            "wk": (n_layers, d, h, hd),
-            "wv": (n_layers, d, h, hd),
-            "wo": (n_layers, h, hd, d),
-            "mlp": {"wi_gate": (n_layers, d, ff), "wi_up": (n_layers, d, ff),
-                    "wo": (n_layers, ff, d)},
-        },
-        "final_ada": (d, 2 * d),
-        "out_proj": (d, lat),
-    }
 
 
 def leaf_std(path: str, shape, init: dict) -> float:
@@ -73,11 +39,12 @@ def _paths(tree, prefix=""):
             yield path, value
 
 
-def make_weights(cfg: dict, seed_key, dtype=jnp.float32):
-    """All leaves from ``seed_key`` in one jitted program on the default
-    device (the key is an argument, so every seed reuses one compile)."""
-    shapes = leaf_shapes(cfg)
-    plan = [(path, shape, leaf_std(path, shape, cfg["init"]))
+def make_weights(shapes: dict, init: dict, seed_key, dtype=jnp.float32):
+    """All leaves of the tree ``shapes`` (nested dicts of shape tuples)
+    from ``seed_key`` in one jitted program on the default device (the key
+    is an argument, so every seed reuses one compile), each with its std
+    from ``init``."""
+    plan = [(path, shape, leaf_std(path, shape, init))
             for path, shape in _paths(shapes)]
 
     def build(key):
