@@ -105,6 +105,10 @@ class ArchConfig:
     latent_dim: int = 0  # per-token continuous latent dim (DiT patch dim)
     num_tokens: int = 0  # latent tokens per sample (DiT: patches per image)
     num_classes: int = 0  # class-conditional diffusion
+    latent_channels: int = 0  # channels of a latent pixel (latent_dim = p*p*c)
+    caption_len: int = 0  # text-conditioned diffusion: caption tokens
+    caption_dim: int = 0  # width of the text encoder's output
+    guidance_scale: float = 0.0  # classifier-free guidance (0 = none)
 
     # ------------------------------------------------------------------------
     @property
@@ -182,7 +186,9 @@ class ArchConfig:
             changes.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=16)
         if self.is_diffusion:
             changes.update(latent_dim=16, num_tokens=16,
-                           num_classes=min(self.num_classes, 16))
+                           num_classes=min(self.num_classes, 16),
+                           caption_len=min(self.caption_len, 12),
+                           caption_dim=min(self.caption_dim, 64))
         if self.m_rope:
             changes.update(m_rope_sections=(4, 6, 6))
         return dataclasses.replace(self, **changes)

@@ -14,6 +14,7 @@ from repro.configs import (
     qwen2_moe_a2_7b,
     qwen2_vl_2b,
     dit_xl,
+    pixart_alpha,
 )
 
 ARCHS = {
@@ -30,11 +31,12 @@ ARCHS = {
         qwen2_moe_a2_7b.CONFIG,
         qwen2_vl_2b.CONFIG,
         dit_xl.CONFIG,
+        pixart_alpha.CONFIG,
     ]
 }
 
-# The ten assigned LM-family architectures (dit-xl is the paper's own extra).
-ASSIGNED = [n for n in ARCHS if n != "dit-xl"]
+# The ten assigned LM-family architectures (the denoisers are extras).
+ASSIGNED = [n for n, cfg in ARCHS.items() if not cfg.is_diffusion]
 
 
 def get_arch(name: str) -> ArchConfig:

@@ -156,9 +156,11 @@ def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
     return -(-rows // sub) * sub * -(-cols // 128) * 128 * itemsize
 
 
-def dit_blocks(pairs: int, n: int, d: int):
+def dit_blocks(pairs: int, n: int, d: int, m: int = 0):
     """(bq, G): query columns a block and (head, batch) pairs a block for
-    ``pairs`` pairs of ``n`` tokens of head size ``d``.
+    ``pairs`` pairs of ``n`` tokens of head size ``d`` attending to ``m``
+    keys (default ``n``; PixArt's cross-attention: 128 padded keys, G
+    heads of one row, 16 at its cell's shapes).
 
     bq is n up to 256 tokens, else 256 (128 where 256 does not divide n).
     G is the largest divisor of ``pairs`` whose blocks fit 16 MiB of VMEM:
@@ -172,10 +174,11 @@ def dit_blocks(pairs: int, n: int, d: int):
     1024   16 heads x 1 row         256   4
     =====  =======================  ====  ==
     """
+    m = m or n
     bq = n if n <= 256 else 256 if n % 256 == 0 else 128
-    io = 2 * (_tile_bytes(d, bq, 2) + 2 * _tile_bytes(d, n, 2)
+    io = 2 * (_tile_bytes(d, bq, 2) + 2 * _tile_bytes(d, m, 2)
               + _tile_bytes(d, bq, 4))
-    work = 2 * _tile_bytes(n, bq, 4) + _tile_bytes(n, bq, 2)
+    work = 2 * _tile_bytes(m, bq, 4) + _tile_bytes(m, bq, 2)
     cap = max(1, 16 * 2 ** 20 // (io + work))
     return bq, max(g for g in range(1, min(pairs, cap) + 1) if pairs % g == 0)
 
@@ -204,3 +207,58 @@ def dit_flash_attention(qt, kt, vt, *, interpret: bool = False):
         interpret=interpret,
     )(*(a.astype(jnp.bfloat16).reshape(h * b, d, n) for a in (qt, kt, vt)))
     return out.reshape(h, b, d, n)
+
+
+def _dit_xflash_kernel(len_ref, qt_ref, kt_ref, vt_ref, ot_ref):
+    """G heads of one batch row: qt (G, d, bq), kt and vt (G, d, m) -> ot
+    (G, d, bq); keys at or past the row's length (len_ref, (1, 1)) are
+    masked."""
+    scale = 1.0 / np.sqrt(qt_ref.shape[1])
+    s = jnp.einsum("gdn,gdq->gnq", kt_ref[...], qt_ref[...],
+                   preferred_element_type=jnp.float32) * scale
+    key = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(key < len_ref[...][None], s, NEG_INF)
+    p = jnp.exp(s - jnp.max(s, axis=1, keepdims=True))
+    vt = vt_ref[...]
+    ot = jnp.einsum("gdn,gnq->gdq", vt, p.astype(vt.dtype),
+                    preferred_element_type=jnp.float32)
+    ot_ref[...] = (ot / jnp.sum(p, axis=1, keepdims=True)).astype(ot_ref.dtype)
+
+
+def dit_cross_flash_attention(qt, kt, vt, lengths, *,
+                              interpret: bool = False):
+    """Non-causal attention of DiT tokens to a padded key sequence
+    (PixArt's cross-attention to its caption, ``_dit_xflash``).
+
+    qt: (H, B, D, N); kt, vt: (H, B, D, M) with M a multiple of 128;
+    lengths: (B,) int32 keys that count in each row -> (H, B, D, N).
+
+    The key-major form of ``dit_flash_attention`` with N_kv != N_q: the
+    caption's keys, padded to 128 lanes, sit in one block, and a grid step
+    holds G heads of one batch row, so one length masks the whole block.
+    The length rides as a (1, 1) int32 block of its row, not as scalar
+    prefetch: under the sampling engine's vmap over lanes a batched
+    scalar-prefetch operand makes Pallas loop over the lanes one kernel
+    call at a time, while a blocked operand gains a grid dimension as q, k
+    and v do.  Precision as ``dit_flash_attention``."""
+    h, b, d, n = qt.shape
+    m = kt.shape[-1]
+    assert m % 128 == 0, m
+    bq, g = dit_blocks(h, n, d, m)
+    out = pl.pallas_call(
+        _dit_xflash_kernel,
+        grid=(b, h // g, n // bq),
+        in_specs=[
+            pl.BlockSpec((None, 1, 1), lambda r, hg, qi: (r, 0, 0)),
+            pl.BlockSpec((g, None, d, bq), lambda r, hg, qi: (hg, r, 0, qi)),
+            pl.BlockSpec((g, None, d, m), lambda r, hg, qi: (hg, r, 0, 0)),
+            pl.BlockSpec((g, None, d, m), lambda r, hg, qi: (hg, r, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((g, None, d, bq),
+                               lambda r, hg, qi: (hg, r, 0, qi)),
+        out_shape=jax.ShapeDtypeStruct((h, b, d, n), qt.dtype),
+        name="_dit_xflash",
+        interpret=interpret,
+    )(lengths.astype(jnp.int32).reshape(b, 1, 1),
+      *(a.astype(jnp.bfloat16) for a in (qt, kt, vt)))
+    return out

@@ -16,6 +16,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref as _ref
 from repro.kernels.flash_attention import (
+    dit_cross_flash_attention as _dit_cross_flash_attention,
     dit_flash_attention as _dit_flash_attention,
     flash_attention as _flash_attention)
 from repro.kernels.flash_decode import flash_decode as _flash_decode
@@ -87,10 +88,23 @@ def _dit_flash_bwd(interpret, qkv, g):
 _dit_flash.defvjp(_dit_flash_fwd, _dit_flash_bwd)
 
 
-def dit_attention(x, wq, wk, wv, wo, *, use_pallas: Optional[bool] = None,
-                  interpret: bool = False):
+def _project(x, ws, biases, layout):
+    """x (B, N, d) through each (d, H, K) projection into ``layout``
+    ("hbkn" or "bnhk"), each plus its (H, K) bias when ``biases`` are
+    given."""
+    outs = [jnp.einsum(f"bnd,dhk->{layout}", x, w) for w in ws]
+    if biases is None:
+        return outs
+    if layout == "hbkn":
+        biases = [b[:, None, :, None] for b in biases]
+    return [o + b for o, b in zip(outs, biases)]
+
+
+def dit_attention(x, wq, wk, wv, wo, *, biases=None,
+                  use_pallas: Optional[bool] = None, interpret: bool = False):
     """The DiT's non-causal self-attention.  x: (B, N, d); wq, wk, wv:
-    (d, H, K); wo: (H, K, d) -> (B, N, d).
+    (d, H, K); wo: (H, K, d); ``biases`` (q, k, v), each (H, K), when the
+    projections have them -> (B, N, d).
 
     On the TPU q, k, v leave their projections as (H, B, K, N), the layout
     of one Pallas flash kernel (``_dit_flash``) that keeps each score tile
@@ -99,11 +113,45 @@ def dit_attention(x, wq, wk, wv, wo, *, use_pallas: Optional[bool] = None,
     einsums', recomputed from q, k, v.  Elsewhere the f32 einsums
     (``ref.dit_attention_ref``)."""
     if _pick(use_pallas):
-        qt, kt, vt = (jnp.einsum("bnd,dhk->hbkn", x, w) for w in (wq, wk, wv))
+        qt, kt, vt = _project(x, (wq, wk, wv), biases, "hbkn")
         ctx = _dit_flash(qt, kt, vt, interpret).astype(x.dtype)
         return jnp.einsum("hbkn,hkd->bnd", ctx, wo)
-    q, k, v = (jnp.einsum("bnd,dhk->bnhk", x, w) for w in (wq, wk, wv))
+    q, k, v = _project(x, (wq, wk, wv), biases, "bnhk")
     ctx = _ref.dit_attention_ref(q, k, v).astype(x.dtype)
+    return jnp.einsum("bnhk,hkd->bnd", ctx, wo)
+
+
+def _dit_xflash(qt, kt, vt, lengths, interpret):
+    spec = _dit_spec(qt.shape)
+    rows = P(spec[1]) if len(spec) else P()
+    return _per_device(functools.partial(_dit_cross_flash_attention,
+                                         interpret=interpret),
+                       qt, kt, vt, lengths, spec=spec,
+                       in_specs=(spec, spec, spec, rows))
+
+
+def dit_cross_attention(x, c, lengths, wq, wk, wv, wo, *, biases=None,
+                        use_pallas: Optional[bool] = None,
+                        interpret: bool = False):
+    """Cross-attention of DiT tokens to a conditioning sequence (PixArt's
+    caption).  x: (B, N, d) queries; c: (B, M, d) keys and values, of
+    which row b counts its first ``lengths[b]`` (the rest are padding);
+    weights and ``biases`` as ``dit_attention`` -> (B, N, d).
+
+    On the TPU the (H, B, K, M) keys and values are padded to 128 keys
+    and the Pallas kernel ``_dit_xflash`` masks each row's padding; its
+    precision is ``_dit_flash``'s.  Elsewhere f32 einsums under the same
+    mask (``ref.dit_cross_attention_ref``)."""
+    if _pick(use_pallas):
+        (qt,) = _project(x, (wq,), biases and biases[:1], "hbkn")
+        kt, vt = _project(c, (wk, wv), biases and biases[1:], "hbkn")
+        pad = -kt.shape[-1] % 128
+        kt, vt = (jnp.pad(a, ((0, 0),) * 3 + ((0, pad),)) for a in (kt, vt))
+        ctx = _dit_xflash(qt, kt, vt, lengths, interpret).astype(x.dtype)
+        return jnp.einsum("hbkn,hkd->bnd", ctx, wo)
+    (q,) = _project(x, (wq,), biases and biases[:1], "bnhk")
+    k, v = _project(c, (wk, wv), biases and biases[1:], "bnhk")
+    ctx = _ref.dit_cross_attention_ref(q, k, v, lengths).astype(x.dtype)
     return jnp.einsum("bnhk,hkd->bnd", ctx, wo)
 
 
@@ -141,13 +189,13 @@ def _row_pin(x, time_axis, dim=0, *, replicate=False):
     return window_constrain(x, time_axis, dim, replicate=replicate)
 
 
-def _per_device(kernel, *args, spec=P()):
+def _per_device(kernel, *args, spec=P(), in_specs=None):
     """Run a Pallas kernel call on every device of the ambient serving mesh.
 
     GSPMD cannot partition a Mosaic kernel, so under a mesh the call goes
-    through ``shard_map`` with ``spec`` on every operand and the output
-    (replicated by default): each device runs it on its own block of the
-    operands.  Under the engine's
+    through ``shard_map`` with ``spec`` on the output and on every operand
+    (``in_specs``, when given, per operand; replicated by default): each
+    device runs it on its own block of the operands.  Under the engine's
     ``vmap(spmd_axis_name=data)`` the request axis becomes a ``data``-sharded
     dim of that shard_map, so each device runs its own requests; on every
     other mesh axis the per-request operands are whole (the ops below keep
@@ -156,7 +204,8 @@ def _per_device(kernel, *args, spec=P()):
     mesh = current_mesh()
     if mesh is None:
         return kernel(*args)
-    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec,) * len(args),
+    return jax.shard_map(kernel, mesh=mesh,
+                         in_specs=in_specs or (spec,) * len(args),
                          out_specs=spec, check_vma=False)(*args)
 
 

@@ -44,6 +44,21 @@ def dit_attention_ref(q, k, v):
     return jnp.einsum("bhnm,bmhk->bnhk", probs, v.astype(jnp.float32))
 
 
+def dit_cross_attention_ref(q, k, v, lengths):
+    """Attention of DiT tokens to a key sequence of which each row counts
+    its first ``lengths`` keys, f32 scores and softmax.  q: (B, N, H, K);
+    k, v: (B, M, H, K); lengths: (B,) -> f32 (B, N, H, K).  Masked scores
+    take the kernels' finite NEG_INF, so a row with no key stays finite."""
+    from repro.kernels.flash_attention import NEG_INF
+    scores = jnp.einsum("bnhk,bmhk->bhnm", q.astype(jnp.float32),
+                        k.astype(jnp.float32))
+    scores = scores / np.sqrt(q.shape[-1])
+    valid = jnp.arange(k.shape[1])[None, :] < lengths[:, None]    # (B, M)
+    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhnm,bmhk->bnhk", probs, v.astype(jnp.float32))
+
+
 # --- GQA flash decode --------------------------------------------------------
 
 

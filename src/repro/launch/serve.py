@@ -1,6 +1,9 @@
 """Serving driver: batched ParaTAA diffusion sampling (the paper's workload).
 
 Each request is (class label | conditioning, seed, optional warm start).
+The denoiser comes from ``--arch`` through one table (``DENOISERS``): the
+class-conditional DiT (``dit-xl``), or PixArt-α (``pixart-alpha``), whose
+requests carry a caption drawn from ``--seed`` and which serves guided.
 Requests run through one ``repro.sampling.SamplingEngine`` per
 (arch, T, solver) configuration, and the engine owns its device placement:
 ``--mesh`` resolves a named mesh from ``repro.launch.mesh`` (with
@@ -64,9 +67,11 @@ solves in a fraction of the cold iteration count:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
+from typing import Callable
 
 
 def _force_host_devices(argv):
@@ -115,23 +120,73 @@ import numpy as np
 from repro.configs.registry import get_arch
 from repro.core import ddim_coeffs, ddpm_coeffs
 from repro.diffusion import dit as dit_mod
+from repro.diffusion import pixart
 from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_mesh, mesh_names
+from repro.models import pdefs
 from repro.obs import (Observability, compile_totals, count_compiles,
                        json_safe)
 from repro.runtime import StragglerMitigator
 from repro.sampling import (Placement, SampleRequest, SamplingEngine,
                             get_sampler)
+from repro.sampling.engine import label_condition
 from repro.serving import (Batcher, BatchingPolicy, EngineKey, EngineRegistry,
                            FaultInjector, RefinePlanner, RefinePolicy,
                            RequestQueue, ResilientServingLoop, ServingLoop)
 
 
-def make_eps_apply(cfg):
-    """Engine-shaped denoiser adapter: (params, x, taus, labels) -> eps."""
+def _dit_eps_apply(cfg):
+    """Engine-shaped DiT adapter: (params, x, taus, labels) -> eps."""
     def eps_apply(params, xw, taus_w, labels):
         return dit_mod.dit_apply(params, cfg, xw, taus_w, labels)
     return eps_apply
+
+
+def _draw_label(rng, cfg) -> dict:
+    return {"label": int(rng.integers(0, cfg.num_classes))}
+
+
+def _draw_caption(rng, cfg) -> dict:
+    """A caption (``pixart.draw_caption``) from a seed drawn from ``rng``,
+    and a token count uniform over [min(10, L), L]."""
+    seed = int(rng.integers(1 << 30))
+    tokens = int(rng.integers(min(10, cfg.caption_len), cfg.caption_len + 1))
+    caption = pixart.draw_caption(seed, (cfg.caption_len, cfg.caption_dim))
+    return {"cond": {"caption": caption, "tokens": np.int32(tokens)}}
+
+
+@dataclasses.dataclass(frozen=True)
+class Denoiser:
+    """What serving one denoiser takes, each from the arch's config: its
+    weight tree, the engine-shaped eps adapter, a request's per-lane
+    condition (``SamplingEngine(condition=...)``), and the conditioning
+    keyword arguments of a request drawn from a generator."""
+    defs: Callable
+    eps_apply: Callable
+    condition: Callable
+    draw: Callable
+
+
+DENOISERS = {
+    "dit-xl": Denoiser(dit_mod.dit_defs, _dit_eps_apply,
+                       lambda cfg: label_condition, _draw_label),
+    "pixart-alpha": Denoiser(pixart.pixart_defs, pixart.make_eps_apply,
+                             pixart.request_condition, _draw_caption),
+}
+
+
+def denoiser(cfg) -> Denoiser:
+    """The table entry of ``cfg``'s architecture (its smoke size too)."""
+    name = cfg.name.removesuffix("-smoke")
+    if name not in DENOISERS:
+        raise SystemExit(f"--arch {name} is not a denoiser this server "
+                         f"runs; known: {sorted(DENOISERS)}")
+    return DENOISERS[name]
+
+
+def make_eps_apply(cfg):
+    """``cfg``'s engine-shaped denoiser: (params, x, taus, cond) -> eps."""
+    return denoiser(cfg).eps_apply(cfg)
 
 
 def make_placement(mesh_name: str = "none", *, data_parallel: int = 0,
@@ -147,12 +202,14 @@ def make_placement(mesh_name: str = "none", *, data_parallel: int = 0,
 
 
 def make_engine(params, cfg, coeffs, spec, *, placement: Placement = None):
-    """One engine at the configuration's own latent shape: ``num_tokens``
-    tokens of ``latent_dim`` (256 x 16 for DiT-XL/2 at 256x256)."""
+    """One engine for ``cfg``'s denoiser at its own latent shape:
+    ``num_tokens`` tokens of ``latent_dim`` (256 x 16 for DiT-XL/2 at
+    256x256)."""
+    den = denoiser(cfg)
     return SamplingEngine(make_eps_apply(cfg), params, coeffs, spec,
                           sample_shape=(cfg.num_tokens, cfg.latent_dim),
-                          placement=placement,
-                          param_defs=dit_mod.dit_defs(cfg))
+                          placement=placement, param_defs=den.defs(cfg),
+                          condition=den.condition(cfg))
 
 
 def serve_batch(engine: SamplingEngine, requests, *, batch_size=None):
@@ -167,16 +224,17 @@ def serve_batch(engine: SamplingEngine, requests, *, batch_size=None):
     results = engine.run_batch(requests, batch_size=batch_size)
     for wall in engine.last_batch_walls:  # one latency sample per dispatch
         straggler.record(wall)
-    stats = [{"label": res.request.label, "iters": res.iters, "nfe": res.nfe,
-              "wall_s": res.wall_s} for res in results]
+    stats = [{"cond": res.request.condition_key, "iters": res.iters,
+              "nfe": res.nfe, "wall_s": res.wall_s} for res in results]
     return jnp.stack([res.x0 for res in results]), stats, straggler
 
 
 def make_requests(args, cfg):
-    """``--requests`` class-conditional requests drawn from ``--seed``."""
+    """``--requests`` requests drawn from ``--seed``: a class label or a
+    caption each, as the denoiser takes."""
     rng = np.random.default_rng(args.seed)
-    return [SampleRequest(label=int(rng.integers(0, cfg.num_classes)),
-                          seed=int(rng.integers(1 << 30)))
+    draw = denoiser(cfg).draw
+    return [SampleRequest(**draw(rng, cfg), seed=int(rng.integers(1 << 30)))
             for _ in range(args.requests)]
 
 
@@ -244,7 +302,7 @@ def simulated_request(rng, cfg, args, *,
         kw["tau"] = args.loose_tau
         if args.quality_steps:
             kw["quality_steps"] = args.quality_steps
-    return SampleRequest(label=int(rng.integers(0, cfg.num_classes)),
+    return SampleRequest(**denoiser(cfg).draw(rng, cfg),
                          seed=int(rng.integers(1 << 30)), **kw)
 
 
@@ -304,10 +362,12 @@ def serve_async(args, cfg, params, placement: Placement):
         return stats["traces"] + stats["stepwise_traces"]
 
     warm = {}
+    warm_request = SampleRequest(**denoiser(cfg).draw(
+        np.random.default_rng(args.seed), cfg))
     for key in keys:  # compile ahead of traffic so p95 is not a jit compile
         engine = registry.get(key)
         registry.warmup(key, slots=loop.batcher.slots_for(engine),
-                        chunk_iters=args.chunk_iters)
+                        request=warm_request, chunk_iters=args.chunk_iters)
         warm[key] = compiled(key)
         print(f"warmed {key.describe()}: {engine.placement.describe()}, "
               f"{warm[key]} program(s) compiled")
@@ -337,7 +397,8 @@ def serve_async(args, cfg, params, placement: Placement):
     retraces = {key: compiled(key) - warm[key] for key in keys}
     stats = []
     for ticket, res in zip(tickets, results):
-        stats.append({"key": ticket.key.describe(), "label": res.request.label,
+        stats.append({"key": ticket.key.describe(),
+                      "cond": res.request.condition_key,
                       "iters": res.iters, "nfe": res.nfe,
                       "early_stopped": res.early_stopped,
                       "latency_s": ticket.latency_s,
@@ -348,7 +409,8 @@ def serve_async(args, cfg, params, placement: Placement):
         early = " early-exit" if res.early_stopped else ""
         two_tier = (f" draft@{ticket.draft_latency_s:.2f}s"
                     if ticket.refines else "")
-        print(f"{ticket.key.describe():>24s} label={res.request.label:4d} "
+        print(f"{ticket.key.describe():>24s} "
+              f"cond={res.request.condition_key} "
               f"iters={res.iters:3d} latency={ticket.latency_s:.2f}s"
               f"{early}{two_tier}")
     if args.chunk_iters:
@@ -568,7 +630,8 @@ def make_params(args):
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
-    params = dit_mod.dit_init(cfg, jax.random.PRNGKey(args.seed))
+    params = pdefs.init_params(denoiser(cfg).defs(cfg),
+                               jax.random.PRNGKey(args.seed))
     if args.ckpt:
         from pathlib import Path
         from repro.ckpt import CheckpointManager
@@ -603,7 +666,7 @@ def main(argv=None):
     for st in stats:
         # wall_s is the wall time of the DISPATCH the request rode in (its
         # latency), not exclusive per-request compute — batch members share it
-        print(f"label={st['label']:4d} iters={st['iters']:3d} "
+        print(f"cond={st['cond']} iters={st['iters']:3d} "
               f"nfe={st['nfe']:5d} batch_wall={st['wall_s']:.2f}s")
     report_dispatches(engine)
     seq_steps = coeffs.T

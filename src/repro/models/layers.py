@@ -113,3 +113,22 @@ def sincos_positions(n: int, dim: int) -> np.ndarray:
     freqs = np.exp(-np.log(10_000.0) * np.arange(half) / half)
     ang = np.arange(n)[:, None] * freqs[None, :]
     return np.concatenate([np.sin(ang), np.cos(ang)], axis=-1).astype(np.float32)
+
+
+def sincos_positions_2d(n: int, dim: int) -> np.ndarray:
+    """Fixed 2-D sincos table (n, dim) over a square grid of n tokens in
+    row-major order (MAE / DiT / PixArt at pe scale 1): the first dim/2
+    columns embed a token's column index, the rest its row index, each as
+    [sin, cos] of index * 10000^(-i / (dim/4))."""
+    side = int(round(np.sqrt(n)))
+    assert side * side == n, f"{n} tokens are not a square grid"
+    quarter = dim // 4
+    freqs = 1.0 / 10_000.0 ** (np.arange(quarter) / quarter)
+
+    def half(pos):
+        ang = pos[:, None] * freqs[None, :]
+        return np.concatenate([np.sin(ang), np.cos(ang)], axis=-1)
+
+    rows, cols = np.divmod(np.arange(n, dtype=np.float64), side)
+    return np.concatenate([half(cols), half(rows)], axis=-1).astype(
+        np.float32)

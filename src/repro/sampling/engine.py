@@ -13,9 +13,10 @@ the ambient ``models.shardctx`` mesh so its activations TP-shard over
 ``model``.  With ``Placement.host()`` (the default) every placement hook is
 an identity and the program is bitwise-identical to the unsharded engine.
 
-Per-request labels, seeds, and warm starts (Sec 4.2) are all data to that
-one program: cold and warm starts share a single compilation because a cold
-start is just ``init = (xi, T_init=T)``.  Batches are padded to a fixed
+Per-request conditions (a class label, or a caption pytree), seeds, and
+warm starts (Sec 4.2) are all data to that one program: cold and warm
+starts share a single compilation because a cold start is just
+``init = (xi, T_init=T)``.  Batches are padded to a fixed
 ``batch_size`` — rounded up to a multiple of the placement's data shards so
 every device holds the same number of request slots — so the engine compiles
 exactly once per (denoiser, T, sampler-spec, batch-size, diagnostics)
@@ -97,7 +98,8 @@ class LaneBank:
     the host<->device boundary.
     """
     state: Any
-    labels: Any                            # (slots,) device int32
+    conds: Any                             # per-lane condition pytree,
+                                           # each leaf (slots, ...) on device
     requests: List[Optional[SampleRequest]]
     slots: int
     chunk_iters: int
@@ -145,7 +147,7 @@ class BankSnapshot:
     the post-recovery tail.
     """
     state: Any                              # numpy SolverState pytree
-    labels: Any                             # (slots,) numpy int32
+    conds: Any                              # numpy per-lane conditions
     requests: List[Optional[SampleRequest]]
     slots: int
     chunk_iters: int
@@ -156,14 +158,15 @@ class BankSnapshot:
         return sum(r is not None for r in self.requests)
 
     def nbytes(self) -> int:
-        leaves = jax.tree.leaves(self.state)
-        return int(sum(a.nbytes for a in leaves) + self.labels.nbytes)
+        leaves = jax.tree.leaves((self.state, self.conds))
+        return int(sum(a.nbytes for a in leaves))
 
 
 class SamplingEngine:
     """Batched sampling executor for one (denoiser, T, solver) configuration.
 
-    eps_apply:    (params, x (n, *sample_shape), taus (n,), labels (n,)) -> eps
+    eps_apply:    (params, x (n, *sample_shape), taus (n,), cond) -> eps,
+                  each leaf of ``cond`` with a leading (n,) axis
     params:       denoiser parameters (closed over by the jitted program);
                   placed onto the mesh at construction when sharded
     coeffs:       SolverCoeffs (fixes T and the DDIM/DDPM schedule)
@@ -185,6 +188,9 @@ class SamplingEngine:
                   the engine onto a shared bundle after construction.
     name:         label for this engine's metric series / trace track
                   (``EngineRegistry`` binds the engine key's description)
+    condition:    request -> that request's per-lane condition, a pytree of
+                  arrays (``None`` -> a vacant lane's); default the class
+                  label as an int32 (``label_condition``)
     """
 
     #: ``last_dispatches`` cap — ``run_batch`` resets the list per call, but
@@ -197,8 +203,10 @@ class SamplingEngine:
                  dtype=jnp.float32, placement: Optional[Placement] = None,
                  param_defs=None, clock: Callable[[], float] = time.monotonic,
                  obs: Optional[Observability] = None,
-                 name: Optional[str] = None):
+                 name: Optional[str] = None,
+                 condition: Optional[Callable] = None):
         self.eps_apply = eps_apply
+        self.condition = condition or label_condition
         self.coeffs = coeffs
         self.spec = spec
         self.sample_shape = tuple(sample_shape)
@@ -275,10 +283,10 @@ class SamplingEngine:
         T = coeffs.T
         eps_apply = self.eps_apply
 
-        def one(params, xi, label, x0, t_init, tau_sq, iter_cap):
+        def one(params, xi, cond, x0, t_init, tau_sq, iter_cap):
             def eps_fn(xw, taus):
-                y = jnp.full((xw.shape[0],), label, jnp.int32)
-                return eps_apply(params, xw, taus, y)
+                return eps_apply(params, xw, taus,
+                                 _per_row(cond, xw.shape[0]))
 
             if spec.is_sequential:
                 traj = _sequential_sample(eps_fn, coeffs, xi, return_traj=True)
@@ -299,19 +307,19 @@ class SamplingEngine:
             # sharding constraint inside the solver gets `data` prepended
             vmap_kw["spmd_axis_name"] = plc.spmd_axes()
 
-        def batched(params, xis, labels, x0s, t_inits, tau_sqs, iter_caps):
+        def batched(params, xis, conds, x0s, t_inits, tau_sqs, iter_caps):
             # executes at trace time only: one increment per compilation
             self.stats["traces"] += 1
             xis = plc.constrain_batch(xis)
-            labels = plc.constrain_batch(labels)
+            conds = jax.tree.map(plc.constrain_batch, conds)
             x0s = plc.constrain_batch(x0s)
             t_inits = plc.constrain_batch(t_inits)
             tau_sqs = plc.constrain_batch(tau_sqs)
             iter_caps = plc.constrain_batch(iter_caps)
             return jax.vmap(
-                lambda xi, lab, x0, ti, tq, ic:
-                    one(params, xi, lab, x0, ti, tq, ic),
-                **vmap_kw)(xis, labels, x0s, t_inits, tau_sqs, iter_caps)
+                lambda xi, cond, x0, ti, tq, ic:
+                    one(params, xi, cond, x0, ti, tq, ic),
+                **vmap_kw)(xis, conds, x0s, t_inits, tau_sqs, iter_caps)
 
         donate = (1, 3) if plc.donate else ()  # xis, x0s: fresh per dispatch
         return jax.jit(batched, donate_argnums=donate)
@@ -338,13 +346,15 @@ class SamplingEngine:
             return jax.ShapeDtypeStruct(shape, dt, **kw)
 
         xis = sds((B, T + 1) + self.sample_shape, jnp.float32)
-        labels = sds((B,), jnp.int32)
+        conds = jax.tree.map(lambda c: sds((B,) + np.shape(c),
+                                           np.asarray(c).dtype),
+                             self.condition(None))
         t_inits = sds((B,), jnp.int32)
         tau_sqs = sds((B,), jnp.float32)
         with plc.activations():
             return self._program(diagnostics).lower(
                 params if params is not None else self.params,
-                xis, labels, xis, t_inits, tau_sqs, t_inits)
+                xis, conds, xis, t_inits, tau_sqs, t_inits)
 
     # -- request packing -----------------------------------------------------
 
@@ -360,12 +370,12 @@ class SamplingEngine:
 
     def _pack(self, requests: Sequence[SampleRequest]):
         T = self.coeffs.T
-        xis, labels, x0s, t_inits = [], [], [], []
+        xis, conds, x0s, t_inits = [], [], [], []
         tau_sqs, iter_caps = [], []
         for req in requests:
             xi = self.draw_request_noise(req)
             xis.append(xi)
-            labels.append(req.label)
+            conds.append(self.condition(req))
             tau_sqs.append(self._tau_sq(req))
             iter_caps.append(self._iter_cap(req))
             if req.init is None:
@@ -381,23 +391,31 @@ class SamplingEngine:
                 # a fully-solved warm start the solver merely verifies
                 t_inits.append(T if req.init.t_init is None
                                else req.init.t_init)
-        return (jnp.stack(xis), jnp.asarray(labels, jnp.int32),
+        return (jnp.stack(xis), jax.tree.map(_stack, *conds),
                 jnp.stack(x0s), jnp.asarray(t_inits, jnp.int32),
                 jnp.asarray(tau_sqs, jnp.float32),
                 jnp.asarray(iter_caps, jnp.int32))
 
     def pack(self, requests: Sequence[SampleRequest]):
-        """Pack requests into the program's (xis, labels, x0s, t_inits,
+        """Pack requests into the program's (xis, conds, x0s, t_inits,
         tau_sqs, iter_caps) arrays, placed onto the request-axis sharding
         when meshed — the (slots, T+1, ...) trajectory arrays additionally
         land on the window sharding when the mesh carries time shards (and
         their row count divides them)."""
-        xis, labels, x0s, t_inits, tau_sqs, iter_caps = \
+        xis, conds, x0s, t_inits, tau_sqs, iter_caps = \
             self._pack(requests)
         xis, x0s = self.placement.place_window(xis, x0s)
-        labels, t_inits, tau_sqs, iter_caps = self.placement.place_batch(
-            labels, t_inits, tau_sqs, iter_caps)
-        return xis, labels, x0s, t_inits, tau_sqs, iter_caps
+        conds, t_inits, tau_sqs, iter_caps = self._place_batch(
+            conds, t_inits, tau_sqs, iter_caps)
+        return xis, conds, x0s, t_inits, tau_sqs, iter_caps
+
+    def _place_batch(self, conds, *arrays):
+        """``place_batch`` of a per-lane condition pytree's leaves and of
+        further per-lane arrays, in one call; returns (conds, *arrays)."""
+        leaves, tree = jax.tree.flatten(conds)
+        placed = self.placement.place_batch(*leaves, *arrays)
+        return (jax.tree.unflatten(tree, placed[:len(leaves)]),
+                *placed[len(leaves):])
 
     # -- execution -----------------------------------------------------------
 
@@ -641,24 +659,23 @@ class SamplingEngine:
                 return jax.vmap(lane_init, **vmap_kw)(*args)
 
         elif kind == "merge":
-            def program(state, fresh, labels, fresh_labels, mask):
+            def program(state, fresh, conds, fresh_conds, mask):
                 self.stats["stepwise_traces"] += 1
 
                 def pick(old, new):
                     m = mask.reshape((-1,) + (1,) * (old.ndim - 1))
                     return plc.constrain_batch(jnp.where(m, new, old))
 
-                labels = plc.constrain_batch(
-                    jnp.where(mask, fresh_labels, labels))
-                return jax.tree.map(pick, state, fresh), labels
+                conds = jax.tree.map(pick, conds, fresh_conds)
+                return jax.tree.map(pick, state, fresh), conds
 
         elif kind == "step":
             shape = self.sample_shape
 
-            def lane_step(params, state, label):
+            def lane_step(params, state, cond):
                 def eps_fn(xw, taus):
-                    y = jnp.full((xw.shape[0],), label, jnp.int32)
-                    return eps_apply(params, xw, taus, y)
+                    return eps_apply(params, xw, taus,
+                                     _per_row(cond, xw.shape[0]))
 
                 return _parataa.step_chunk(eps_fn, coeffs, cfg, state,
                                            chunk_iters, sample_shape=shape)
@@ -666,12 +683,12 @@ class SamplingEngine:
             vmap_kw = {"spmd_axis_name": plc.spmd_axes()} \
                 if plc.is_sharded else {}
 
-            def program(params, state, labels):
+            def program(params, state, conds):
                 self.stats["stepwise_traces"] += 1
                 state = self._constrain_state(state)
-                labels = plc.constrain_batch(labels)
-                out = jax.vmap(lambda s, lab: lane_step(params, s, lab),
-                               **vmap_kw)(state, labels)
+                conds = self._constrain_state(conds)
+                out = jax.vmap(lambda s, c: lane_step(params, s, c),
+                               **vmap_kw)(state, conds)
                 # piggybacked poll: one packed (slots, 5) scheduling array
                 # rides out of the chunk, so the host never issues a
                 # separate per-field fetch to learn who finished; column 4
@@ -718,6 +735,7 @@ class SamplingEngine:
         self.spec.check_request_flags(
             warm_start=request.init is not None,
             solver_overrides=request.has_solver_overrides)
+        self.condition(request)
         if request.init is not None:
             self._validate_init(request.init)
 
@@ -763,9 +781,10 @@ class SamplingEngine:
             xi = self.draw_request_noise(SampleRequest())
             with self.placement.activations():
                 state = self._stepwise_program("open", B)(xi)
-            (labels,) = self.placement.place_batch(
-                jnp.zeros((B,), jnp.int32))
-        bank = LaneBank(state=state, labels=labels, requests=[None] * B,
+            (conds,) = self._place_batch(jax.tree.map(
+                lambda c: jnp.broadcast_to(jnp.asarray(c), (B,) + np.shape(c)),
+                self.condition(None)))
+        bank = LaneBank(state=state, conds=conds, requests=[None] * B,
                         slots=B, chunk_iters=chunk_iters)
         bank.pack_s += self._clock() - t0
         return bank
@@ -797,21 +816,20 @@ class SamplingEngine:
             packed = self._pack(requests)       # (k, ...) — k PRNG draws
             pos = {lane: i for i, lane in enumerate(lanes)}
             idx = np.asarray([pos.get(j, 0) for j in range(bank.slots)])
-            xis, labels, x0s, t_inits, tau_sqs, iter_caps = (
-                jnp.take(a, idx, axis=0) for a in packed)
+            xis, conds, x0s, t_inits, tau_sqs, iter_caps = jax.tree.map(
+                lambda a: jnp.take(a, idx, axis=0), packed)
             # lanes outside the refill keep their OLD state (merge mask), so
             # the repeated filler rows never land anywhere
             untouched = np.asarray([j not in pos
                                     for j in range(bank.slots)])
             xis, x0s = self.placement.place_window(xis, x0s)
-            t_inits, tau_sqs, iter_caps, labels, mask = \
-                self.placement.place_batch(t_inits, tau_sqs, iter_caps,
-                                           labels, jnp.asarray(~untouched))
+            conds, t_inits, tau_sqs, iter_caps, mask = self._place_batch(
+                conds, t_inits, tau_sqs, iter_caps, jnp.asarray(~untouched))
             with self.placement.activations():
                 fresh = self._stepwise_program("init")(
                     xis, x0s, t_inits, tau_sqs, iter_caps)
-                bank.state, bank.labels = self._stepwise_program("merge")(
-                    bank.state, fresh, bank.labels, labels, mask)
+                bank.state, bank.conds = self._stepwise_program("merge")(
+                    bank.state, fresh, bank.conds, conds, mask)
         for lane, req in zip(lanes, requests):
             bank.requests[lane] = req
         # the pre-merge summary no longer describes the refilled lanes —
@@ -838,7 +856,7 @@ class SamplingEngine:
             with self.placement.activations():
                 bank.state, summary = self._stepwise_program(
                     "step", bank.chunk_iters)(self.params, bank.state,
-                                              bank.labels)
+                                              bank.conds)
         bank.summary = summary
         bank.poll_cache = None
         if hasattr(summary, "copy_to_host_async"):
@@ -1002,11 +1020,10 @@ class SamplingEngine:
         visible in the same ledger as the steady-state protocol."""
         with self._tracer.span("stepwise.fetch_bank", tid=self.name,
                                slots=bank.slots, occupied=bank.occupied):
-            state, labels = jax.device_get((bank.state, bank.labels))
-        state = jax.tree.map(np.asarray, state)
-        labels = np.asarray(labels)
+            state, conds = jax.device_get((bank.state, bank.conds))
+        state, conds = jax.tree.map(np.asarray, (state, conds))
         counters = {k: getattr(bank, k) for k in self._CARRIED_COUNTERS}
-        snap = BankSnapshot(state=state, labels=labels,
+        snap = BankSnapshot(state=state, conds=conds,
                             requests=list(bank.requests), slots=bank.slots,
                             chunk_iters=bank.chunk_iters, counters=counters)
         self._count_fetch(bank, snap.nbytes(), polls=1)
@@ -1036,9 +1053,9 @@ class SamplingEngine:
             def place(leaf):
                 (out,) = self.placement.place_batch(jnp.asarray(leaf))
                 return out
-            state = jax.tree.map(place, snapshot.state)
-            labels = place(snapshot.labels)
-        bank = LaneBank(state=state, labels=labels,
+            state, conds = jax.tree.map(place, (snapshot.state,
+                                                snapshot.conds))
+        bank = LaneBank(state=state, conds=conds,
                         requests=list(snapshot.requests), slots=B,
                         chunk_iters=int(chunk_iters or snapshot.chunk_iters),
                         **snapshot.counters)
@@ -1062,6 +1079,28 @@ class SamplingEngine:
     def throughput(self) -> float:
         """Requests per second over every batch this engine has run."""
         return self.stats["requests"] / max(self.stats["wall_s"], 1e-9)
+
+
+def label_condition(request: Optional[SampleRequest]):
+    """The per-lane condition of a label-conditioned denoiser (the DiT):
+    the request's class label as an int32, 0 for a vacant lane."""
+    return np.int32(0 if request is None else request.label)
+
+
+def _stack(*leaves):
+    """One condition leaf of several requests as a (k, ...) device array:
+    host values stacked on the host and sent once, device values (a
+    caption made on the device) stacked there."""
+    if all(isinstance(c, (np.ndarray, np.generic)) for c in leaves):
+        return jnp.asarray(np.stack(leaves))
+    return jnp.stack(leaves)
+
+
+def _per_row(cond, rows: int):
+    """A lane's condition, each leaf repeated for the lane's ``rows``
+    denoiser rows (a window of ParaTAA rows, or the one sequential row)."""
+    return jax.tree.map(lambda c: jnp.broadcast_to(c, (rows,) + c.shape),
+                        cond)
 
 
 def _is_abstract(params) -> bool:

@@ -1,15 +1,21 @@
 """Typed request/result surface of the unified sampling API.
 
 A ``SampleRequest`` is everything the serving layer knows about one sample:
-conditioning label, RNG seed, and an optional warm start (Sec 4.2 trajectory
-initialization).  A ``SampleResult`` is everything a caller may want back:
-the x0 latent, the full trajectory, solver statistics, and (when requested)
-per-iteration diagnostics.
+its conditioning (a class label, or a tensor such as a caption), RNG seed,
+and an optional warm start (Sec 4.2 trajectory initialization).  A
+``SampleResult`` is everything a caller may want back: the x0 latent, the
+full trajectory, solver statistics, and (when requested) per-iteration
+diagnostics.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+import functools
+import hashlib
+from typing import Any, Dict, Hashable, Optional
+
+import jax
+import numpy as np
 
 #: per-iteration recordings produced by a diagnostics=True run, in the order
 #: they appear in SampleResult.diagnostics (single source for api + engine)
@@ -44,6 +50,13 @@ class WarmStart:
 class SampleRequest:
     """One sampling request: (conditioning, seed, optional warm start,
     optional per-request solver budget).
+
+    The conditioning is ``label``, a class label, for a label-conditioned
+    denoiser (the DiT), or ``cond``, a pytree of arrays such as a caption
+    and its token count, for one that takes a tensor (PixArt-α); the
+    engine's ``condition`` turns either into the per-lane arrays its
+    programs carry, and ``condition_key`` into what the warm-start cache
+    keys on.
 
     ``arrival_time``, ``priority``, and ``preemptible`` are serving
     metadata carried on the request itself so batching layers never need a
@@ -86,6 +99,22 @@ class SampleRequest:
     #: ``result()``.  ``dataclasses.replace``-based continuations (refine,
     #: preemption resubmits) inherit it automatically.
     timeout_s: Optional[float] = None
+    cond: Any = None
+
+    @functools.cached_property
+    def condition_key(self) -> Hashable:
+        """The request's condition as the warm-start cache keys it: the
+        label, or, when the request carries ``cond``, a digest of each
+        leaf's dtype, shape and bytes (a caption and its token count), so
+        two requests share a key exactly when their conditions are equal."""
+        if self.cond is None:
+            return self.label
+        digest = hashlib.blake2b(digest_size=16)
+        for leaf in jax.tree.leaves(self.cond):
+            a = np.asarray(leaf)
+            digest.update(f"{a.dtype}{a.shape}".encode())
+            digest.update(np.ascontiguousarray(a).tobytes())
+        return digest.hexdigest()
 
     @property
     def has_solver_overrides(self) -> bool:

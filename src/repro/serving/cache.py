@@ -11,7 +11,10 @@ Policy, beyond the PR-4 skeleton's exact-label LRU:
 
   * entries key on ``(label, seed)`` — the full identity of one solved
     request — so repeat traffic warm-starts from ITS OWN trajectory
-    (the strongest init: same condition, same noise draw);
+    (the strongest init: same condition, same noise draw).  The "label"
+    is the request's ``condition_key``: its class label, or a digest of
+    a conditioning tensor (a caption and its length), so requests with
+    different captions never share an entry;
   * lookup degrades gracefully: exact ``(label, seed)`` -> most-recent
     same-label entry (a conditioning neighbor under a different noise
     draw) -> nearest label within a configurable ``neighborhood`` distance
@@ -113,7 +116,7 @@ class TrajectoryCache:
         nbytes = _traj_nbytes(result.trajectory)
         if self.max_bytes is not None and nbytes > self.max_bytes:
             return False
-        key = (result.request.label, result.request.seed)
+        key = (result.request.condition_key, result.request.seed)
         with self._lock:
             old = self._store.pop(key, None)
             if old is not None:

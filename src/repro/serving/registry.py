@@ -123,11 +123,12 @@ class EngineRegistry:
         """``RequestQueue(warm_start=...)`` hook: the Sec 4.2 cache
         auto-population point.  A request that already carries an ``init``
         keeps it; otherwise the key's cache answers with its best match
-        (exact (label, seed) -> same label -> neighborhood), or None for a
-        cold start."""
+        (exact (condition, seed) -> same condition -> neighborhood), or None
+        for a cold start."""
         if request.init is not None:
             return None
-        return self.cache(key).lookup(request.label, seed=request.seed)
+        return self.cache(key).lookup(request.condition_key,
+                                      seed=request.seed)
 
     def warmup(self, key: EngineKey, *, slots: int,
                request: Optional[SampleRequest] = None,

@@ -9,7 +9,9 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels import ops
-from repro.kernels.flash_attention import (dit_blocks, dit_flash_attention,
+from repro.kernels.flash_attention import (dit_blocks,
+                                           dit_cross_flash_attention,
+                                           dit_flash_attention,
                                            flash_attention)
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.ssd_scan import ssd_scan
@@ -68,6 +70,54 @@ def test_dit_flash_attention_matches_einsum(slots, h, b, n):
     err = float(jnp.max(jnp.abs(out - want)))
     assert 0 < err <= 2 * rounding, (err, rounding)
     assert out.shape == want.shape and out.dtype == want.dtype
+
+
+@pytest.mark.parametrize("m", [1, 37, 120])
+def test_dit_cross_attention_matches_masked_einsum(m):
+    """The cross-attention kernel, vmapped over slots as the engine calls
+    it, keys padded to 128 and each row masked past its own key count,
+    against the f32 einsums under the same mask (``ops`` off the TPU): no
+    farther off than twice what rounding q, k, v to bf16 moves the
+    einsums themselves."""
+    slots, h, b, k, n = 2, 4, 3, 72, 256
+    x = jax.random.normal(jax.random.fold_in(KEY, m), (slots, b, n, 48))
+    c = jax.random.normal(jax.random.fold_in(KEY, m + 1), (slots, b, m, 48))
+    w = [jax.random.normal(jax.random.fold_in(KEY, i), (48, h, k)) / 7
+         for i in range(3)]
+    wo = jnp.eye(h * k).reshape(h, k, h * k)        # the context itself
+    biases = [jax.random.normal(jax.random.fold_in(KEY, 5 + i), (h, k))
+              for i in range(3)]
+    lengths = jnp.asarray([[m, max(m // 2, 1), 1], [1, m, m]], jnp.int32)
+
+    def attend(x, c, w, **kw):
+        return jax.vmap(lambda x, c, lens: ops.dit_cross_attention(
+            x, c, lens, *w, wo, biases=biases, **kw))(x, c, lengths)
+
+    want = attend(x, c, w, use_pallas=False)
+    out = attend(x, c, w, use_pallas=True, interpret=True)
+    r16 = [a.astype(jnp.bfloat16).astype(jnp.float32) for a in (x, c, *w)]
+    rounding = float(jnp.max(jnp.abs(
+        attend(*r16[:2], r16[2:], use_pallas=False) - want)))
+    err = float(jnp.max(jnp.abs(out - want)))
+    assert 0 < err <= 2 * rounding, (err, rounding)
+    assert out.shape == want.shape == (slots, b, n, h * k)
+
+
+def test_dit_cross_attention_ignores_padded_keys():
+    """Keys past a row's count change nothing, in the kernel and in the
+    einsums: the mask, not the padding's values, decides."""
+    b, n, m, d = 2, 128, 40, 32
+    x = jax.random.normal(KEY, (b, n, d))
+    c = jax.random.normal(jax.random.fold_in(KEY, 1), (b, m, d))
+    w = [jax.random.normal(jax.random.fold_in(KEY, i), (d, 2, 16)) / 6
+         for i in range(2, 5)]
+    wo = jax.random.normal(jax.random.fold_in(KEY, 6), (2, 16, d))
+    lengths = jnp.asarray([7, 23], jnp.int32)
+    noisy = c.at[:, 23:].add(50.0).at[0, 7:].set(-3.0)
+    for kw in ({"use_pallas": False}, {"use_pallas": True, "interpret": True}):
+        a = ops.dit_cross_attention(x, c, lengths, *w, wo, **kw)
+        z = ops.dit_cross_attention(x, noisy, lengths, *w, wo, **kw)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(z))
 
 
 @pytest.mark.parametrize("slots,h,b,n", DIT_SHAPES[:2])

@@ -460,7 +460,7 @@ def test_step_program_names_its_scopes(solver, scopes):
                                get_sampler(solver))
     bank = engine.stepwise_open(2, chunk_iters=1)
     text = engine._stepwise_program("step", 1).lower(
-        engine.params, bank.state, bank.labels).compile().as_text()
+        engine.params, bank.state, bank.conds).compile().as_text()
     names = set(re.findall(r'op_name="([^"]*)"', text))
     for scope in scopes:
         assert any(f"/{scope}/" in n for n in names), scope

@@ -6,13 +6,15 @@ mode cannot (block shapes off the (8, 128) tiling, unsupported in-kernel
 ops, VMEM overruns) without a chip.  TAA shapes are DiT-XL/2 at 256x256: T=25
 rows of D = 256 tokens x 16 = 4096, history m=3, vmapped over 4 request
 slots the way the sampling engine calls them; the DiT attention kernel
-runs at both benchmark cells' shapes.  Nothing runs; each test only
+runs at both benchmark cells' shapes, PixArt's cross-attention kernel at
+its cell's.  Nothing runs; each test only
 compiles (about a second a kernel, ten for a step program).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every pytest worker
 imports this file.
 """
+import functools
 import re
 
 import jax
@@ -145,3 +147,64 @@ def test_step_program_runs_dit_attention_in_the_kernel(one_chip, solver,
     assert all(scope_path(op).endswith("dit/attn") for op in flash)
     taa = {"taa": {"%_taa_gram", "%_taa_apply"}, "seq": set()}[solver]
     assert _names(kernels) == {"%_dit_flash"} | taa
+
+
+def test_dit_xflash_compiles_for_v5e(one_chip):
+    """PixArt's cross-attention kernel at its cell's shapes: 8 slots of 2
+    guided rows, 16 heads of 72, 1024 queries against the caption's 120
+    keys padded to 128, each row masked past its own count."""
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                            sharding=one_chip)
+    args = (f32((8, 16, 2, 72, 1024)), f32((8, 16, 2, 72, 128)),
+            f32((8, 16, 2, 72, 128)),
+            jax.ShapeDtypeStruct((8, 2), jnp.int32, sharding=one_chip))
+    lowered = jax.jit(jax.vmap(
+        flash_attention.dit_cross_flash_attention)).lower(*args)
+    assert 'kernel_name = "_dit_xflash"' in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert re.search(r"%\S*_dit_xflash\S* = .*tpu_custom_call", text)
+
+
+def test_pixart_step_program_runs_both_kernels(one_chip, monkeypatch):
+    """In PixArt's seq step program every layer's self-attention is one
+    ``%_dit_flash.N`` under ``dit/attn`` and its cross-attention one
+    ``%_dit_xflash.N`` under ``dit/xattn`` (what ``xattn_share`` and
+    ``xattn_roofline`` read).  Full depth, smoke widths."""
+    import dataclasses
+    import sys
+    from pathlib import Path
+    from repro.configs.registry import ARCHS
+    from repro.core import ddim_coeffs
+    from repro.diffusion import pixart
+    from repro.kernels import ops
+    from repro.launch import serve
+    from repro.models.pdefs import is_def
+    from repro.sampling import get_sampler
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "bench"))
+    from scopes import scope_path
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    layers = ARCHS["pixart-alpha"].num_layers
+    cfg = dataclasses.replace(ARCHS["pixart-alpha"].reduced(),
+                              num_layers=layers)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = jax.tree.map(lambda d: sds(d.shape, jnp.float32),
+                          pixart.pixart_defs(cfg), is_leaf=is_def)
+    engine = serve.make_engine(params, cfg, ddim_coeffs(8),
+                               get_sampler("seq"))
+    xi = sds((9,) + tuple(engine.sample_shape), jnp.float32)
+    state = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        engine._stepwise_program("open", SLOTS), xi))
+    conds = {"caption": sds((SLOTS, cfg.caption_len, cfg.caption_dim),
+                            jnp.float32),
+             "tokens": sds((SLOTS,), jnp.int32)}
+    text = engine._stepwise_program("step", 1).lower(
+        params, state, conds).compile().as_text()
+    kernels = re.findall(r"(%\S+) = \S+ custom-call\([^)]*\), "
+                         r'custom_call_target="tpu_custom_call".*?'
+                         r'op_name="([^"]*)"', text)
+    scopes = {"%_dit_flash.": "dit/attn", "%_dit_xflash.": "dit/xattn"}
+    for prefix, scope in scopes.items():
+        ops_ = [op for k, op in kernels if k.startswith(prefix)]
+        assert len(ops_) == layers, prefix
+        assert all(scope_path(op).endswith(scope) for op in ops_)
+    assert _names(kernels) == {"%_dit_flash", "%_dit_xflash"}
