@@ -65,8 +65,9 @@ def dit_apply(params, cfg: ArchConfig, latents, t, y=None, *, remat: bool = Fals
     Named scopes put each part's ops under ``dit/<part>`` in the HLO
     metadata (``op_name``), so a device trace splits the step by part:
     ``embed`` (input projection, positions, t/y conditioning), ``weights``
-    (slicing layer i out of the stacked block weights), ``ada`` (adaLN:
-    the conditioning projection, layer norm and modulation), ``attn``
+    (slicing layer i out of stacked block weights; the serving path holds
+    them per layer, ``serve.make_engine``, and has no such ops), ``ada``
+    (adaLN: the conditioning projection, layer norm and modulation), ``attn``
     (q/k/v/o projections, scores, softmax, context and the gated
     residual), ``mlp`` (the MLP and its gated residual), ``final``."""
     with jax.named_scope("dit"):
@@ -106,9 +107,7 @@ def _dit_apply(params, cfg: ArchConfig, latents, t, y, *, remat: bool):
     # are counted exactly by the dry-run's cost analysis
     fn = jax.checkpoint(block) if remat else block
     for i in range(cfg.num_layers):
-        with jax.named_scope("weights"):
-            p = jax.tree.map(lambda t: t[i], params["blocks"])
-        x = fn(p, x)
+        x = fn(pdefs.layer_weights(params["blocks"], i), x)
     with jax.named_scope("final"):
         fa = cond @ params["final_ada"]
         sh, sc = jnp.split(fa, 2, axis=-1)

@@ -19,9 +19,11 @@ final layer modulates by its own (2, d) table plus t_emb and projects to
 p x p x 2 channels a token: eps and the learned sigma.
 
 Named scopes sit under ``dit``, as the DiT's do, so a device trace splits
-a step by part: ``embed``, ``caption``, ``ada``, ``weights``, ``attn``
-(self-attention, through ``ops.dit_attention``), ``xattn``, ``mlp``,
-``final`` and ``guide`` (the guided adapter's doubling and combination).
+a step by part: ``embed``, ``caption``, ``ada``, ``weights`` (slicing
+layer i out of stacked blocks; the serving path holds them per layer and
+has no such ops, ``serve.make_engine``), ``attn`` (self-attention,
+through ``ops.dit_attention``), ``xattn``, ``mlp``, ``final`` and
+``guide`` (the guided adapter's doubling and combination).
 """
 from __future__ import annotations
 
@@ -33,6 +35,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.kernels import ops
+from repro.models import pdefs
 from repro.models.layers import (layernorm_noaffine, sincos_positions_2d,
                                  sinusoidal_embed)
 from repro.models.pdefs import ParamDef, stack_defs
@@ -157,9 +160,7 @@ def _pixart_apply(params, cfg: ArchConfig, latents, t, cond):
                                                   p["fc2"], p["b2"])
 
     for i in range(cfg.num_layers):
-        with jax.named_scope("weights"):
-            p = jax.tree.map(lambda w: w[i], params["blocks"])
-        x = block(p, x)
+        x = block(pdefs.layer_weights(params["blocks"], i), x)
     with jax.named_scope("final"):
         mods = params["final_table"][None] + temb[:, None, :]
         x = _modulate(layernorm_noaffine(x), mods[:, 0], mods[:, 1])

@@ -116,6 +116,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.registry import get_arch
 from repro.core import ddim_coeffs, ddpm_coeffs
@@ -201,14 +202,55 @@ def make_placement(mesh_name: str = "none", *, data_parallel: int = 0,
     return Placement.for_mesh(mesh, donate=donate)
 
 
+_UNSTACK = jax.jit(pdefs.unstack)
+
+
+def _layer_of(s: jax.ShapeDtypeStruct) -> jax.ShapeDtypeStruct:
+    """One layer of an abstract stacked leaf, sharded as its slices are
+    (the ``layers`` dim is never sharded)."""
+    sharding = s.sharding
+    if isinstance(sharding, NamedSharding):
+        sharding = NamedSharding(sharding.mesh, P(*sharding.spec[1:]))
+    return jax.ShapeDtypeStruct(s.shape[1:], s.dtype, sharding=sharding)
+
+
+def serving_weights(params, cfg, placement: Placement = None):
+    """The weights an engine serves ``cfg``'s denoiser from: ``params``
+    with its stacked ``blocks`` (the stored layout of init, checkpoints
+    and training) as a tuple of per-layer trees, made once by one jitted
+    call on ``placement``'s devices.  A layer loop over stacked blocks
+    slices layer i out inside the compiled step, which on a TPU writes a
+    copy of every block weight at every step (bf16 for the MXU, f32 for
+    ``ada``); per-layer arrays feed the dots as they are.  Abstract params
+    (ShapeDtypeStructs) come back abstract, with the slices' shardings;
+    ``None`` and a tree already per layer come back as they are."""
+    if params is None or isinstance(params["blocks"], tuple):
+        return params
+    blocks = params["blocks"]
+    leaf = jax.tree.leaves(blocks)[0]
+    if isinstance(leaf, jax.ShapeDtypeStruct):
+        layer = jax.tree.map(_layer_of, blocks)
+        return dict(params, blocks=(layer,) * leaf.shape[0])
+    if placement is not None:
+        blocks = placement.shard_params(blocks,
+                                        denoiser(cfg).defs(cfg)["blocks"])
+    return dict(params, blocks=_UNSTACK(blocks))
+
+
 def make_engine(params, cfg, coeffs, spec, *, placement: Placement = None):
     """One engine for ``cfg``'s denoiser at its own latent shape:
     ``num_tokens`` tokens of ``latent_dim`` (256 x 16 for DiT-XL/2 at
-    256x256)."""
+    256x256), serving per-layer block weights (``serving_weights``; the
+    defs the engine places them by are unstacked to match)."""
     den = denoiser(cfg)
-    return SamplingEngine(make_eps_apply(cfg), params, coeffs, spec,
+    defs = den.defs(cfg)
+    return SamplingEngine(make_eps_apply(cfg),
+                          serving_weights(params, cfg, placement),
+                          coeffs, spec,
                           sample_shape=(cfg.num_tokens, cfg.latent_dim),
-                          placement=placement, param_defs=den.defs(cfg),
+                          placement=placement,
+                          param_defs=dict(defs,
+                                          blocks=pdefs.unstack(defs["blocks"])),
                           condition=den.condition(cfg))
 
 
@@ -309,6 +351,8 @@ def simulated_request(rng, cfg, args, *,
 def serve_async(args, cfg, params, placement: Placement):
     """Drive the ``repro.serving`` stack with a simulated request stream."""
     keys = mixed_engine_keys(args)
+    # one per-layer weight tree, shared by every engine the factories make
+    params = serving_weights(params, cfg, placement)
     registry = EngineRegistry(make_engine_factory(cfg, params, args,
                                                   placement))
     policy = BatchingPolicy(max_batch=args.batch_size or 8,

@@ -98,6 +98,35 @@ def stack_defs(defs, n: int):
     )
 
 
+def unstack(stacked) -> tuple:
+    """A stacked tree (``stack_defs``' form) as a tuple of its layers, one
+    tree each: a ParamDef loses its leading ``layers`` dim and axis, an
+    array is sliced.  Jit it for arrays: the slices then stay on the
+    stacked leaves' devices, with the shardings of the slices."""
+    leaves = jax.tree.leaves(stacked, is_leaf=is_def)
+    n = leaves[0].shape[0]
+
+    def take(x, i):
+        if is_def(x):
+            assert x.axes[0] == "layers", x
+            return ParamDef(x.shape[1:], x.axes[1:], x.init, x.scale, x.dtype)
+        return x[i]
+
+    return tuple(jax.tree.map(lambda x: take(x, i), stacked, is_leaf=is_def)
+                 for i in range(n))
+
+
+def layer_weights(blocks, i: int):
+    """Layer ``i``'s weights from a model's ``blocks``: an index into a
+    tuple of per-layer trees (``unstack``'s form, which serving holds), or
+    a slice of every stacked leaf, under the ``weights`` scope, which in a
+    compiled program copies each layer's weights out of the stack."""
+    if isinstance(blocks, tuple):
+        return blocks[i]
+    with jax.named_scope("weights"):
+        return jax.tree.map(lambda w: w[i], blocks)
+
+
 def _leaf_key(key, path) -> jax.Array:
     # crc32, not hash(): str hashes are salted per process, so the same seed
     # would draw different weights in every run

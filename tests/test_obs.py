@@ -442,7 +442,7 @@ def test_compile_counters_name_the_function():
 
 
 @pytest.mark.parametrize("solver,scopes", [
-    ("taa", ("dit/attn", "dit/mlp", "dit/ada", "dit/weights",
+    ("taa", ("dit/attn", "dit/mlp", "dit/ada",
              "parataa/denoise", "parataa/residual", "parataa/anderson")),
     ("seq", ("dit/attn", "dit/mlp", "parataa/denoise")),
 ])
@@ -466,4 +466,6 @@ def test_step_program_names_its_scopes(solver, scopes):
         assert any(f"/{scope}/" in n for n in names), scope
     # the DiT runs inside the solver's denoise scope
     assert any("/parataa/denoise/dit/attn/" in n for n in names)
+    # served per layer, the step slices no weights out of a stack
+    assert not any("/dit/weights/" in n for n in names)
 

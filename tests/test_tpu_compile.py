@@ -14,11 +14,13 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every pytest worker
 imports this file.
 """
+import dataclasses
 import functools
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -72,31 +74,36 @@ def test_taa_round_compiles_for_v5e(one_chip, mode):
              (SLOTS, M, T, D), (SLOTS, T), (SLOTS, T))
 
 
-def _step_program_kernels(one_chip, cfg, solver):
-    """(instruction name, op_name) of every Pallas call in the engine's
-    stepwise step program, compiled for the described chip."""
+def _step_program(one_chip, cfg, solver):
+    """The compiled HLO text of the engine's stepwise step program for
+    ``cfg``'s denoiser, served as ``serve.make_engine`` serves it (the
+    block weights per layer), compiled for the described chip."""
     from repro.core import ddim_coeffs
-    from repro.diffusion import dit
     from repro.launch import serve
     from repro.models.pdefs import is_def
     from repro.sampling import get_sampler
-    params = jax.tree.map(
-        lambda d: jax.ShapeDtypeStruct(d.shape, jnp.float32,
-                                       sharding=one_chip),
-        dit.dit_defs(cfg), is_leaf=is_def)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = jax.tree.map(lambda d: sds(d.shape, jnp.float32),
+                          serve.denoiser(cfg).defs(cfg), is_leaf=is_def)
     engine = serve.make_engine(params, cfg, ddim_coeffs(8),
                                get_sampler(solver, use_pallas=True))
-    xi = jax.ShapeDtypeStruct((9,) + tuple(engine.sample_shape),
-                              jnp.float32)
-    state = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        jax.eval_shape(engine._stepwise_program("open", SLOTS), xi))
-    labels = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
-    text = engine._stepwise_program("step", 1).lower(
-        params, state, labels).compile().as_text()
+    xi = sds((9,) + tuple(engine.sample_shape), jnp.float32)
+    state = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        engine._stepwise_program("open", SLOTS), xi))
+    conds = jax.tree.map(
+        lambda c: sds((SLOTS,) + np.shape(c), np.asarray(c).dtype),
+        engine.condition(None))
+    return engine._stepwise_program("step", 1).lower(
+        engine.params, state, conds).compile().as_text()
+
+
+def _step_program_kernels(one_chip, cfg, solver):
+    """(instruction name, op_name) of every Pallas call in the engine's
+    stepwise step program, compiled for the described chip."""
     return re.findall(r"(%\S+) = \S+ custom-call\([^)]*\), "
                       r'custom_call_target="tpu_custom_call".*?'
-                      r'op_name="([^"]*)"', text)
+                      r'op_name="([^"]*)"',
+                      _step_program(one_chip, cfg, solver))
 
 
 def _names(kernels):
@@ -131,7 +138,6 @@ def test_step_program_runs_dit_attention_in_the_kernel(one_chip, solver,
     """On a TPU every DiT layer's attention is one ``%_dit_flash.N`` call
     under the ``dit/attn`` scope (what ``attn_share`` reads), and the only
     ``%_taa_`` calls are the solver's.  Full depth, smoke widths."""
-    import dataclasses
     import sys
     from pathlib import Path
     from repro.configs.registry import ARCHS
@@ -170,41 +176,46 @@ def test_pixart_step_program_runs_both_kernels(one_chip, monkeypatch):
     ``%_dit_flash.N`` under ``dit/attn`` and its cross-attention one
     ``%_dit_xflash.N`` under ``dit/xattn`` (what ``xattn_share`` and
     ``xattn_roofline`` read).  Full depth, smoke widths."""
-    import dataclasses
     import sys
     from pathlib import Path
     from repro.configs.registry import ARCHS
-    from repro.core import ddim_coeffs
-    from repro.diffusion import pixart
     from repro.kernels import ops
-    from repro.launch import serve
-    from repro.models.pdefs import is_def
-    from repro.sampling import get_sampler
     sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "bench"))
     from scopes import scope_path
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     layers = ARCHS["pixart-alpha"].num_layers
     cfg = dataclasses.replace(ARCHS["pixart-alpha"].reduced(),
                               num_layers=layers)
-    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    params = jax.tree.map(lambda d: sds(d.shape, jnp.float32),
-                          pixart.pixart_defs(cfg), is_leaf=is_def)
-    engine = serve.make_engine(params, cfg, ddim_coeffs(8),
-                               get_sampler("seq"))
-    xi = sds((9,) + tuple(engine.sample_shape), jnp.float32)
-    state = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
-        engine._stepwise_program("open", SLOTS), xi))
-    conds = {"caption": sds((SLOTS, cfg.caption_len, cfg.caption_dim),
-                            jnp.float32),
-             "tokens": sds((SLOTS,), jnp.int32)}
-    text = engine._stepwise_program("step", 1).lower(
-        params, state, conds).compile().as_text()
-    kernels = re.findall(r"(%\S+) = \S+ custom-call\([^)]*\), "
-                         r'custom_call_target="tpu_custom_call".*?'
-                         r'op_name="([^"]*)"', text)
+    kernels = _step_program_kernels(one_chip, cfg, "seq")
     scopes = {"%_dit_flash.": "dit/attn", "%_dit_xflash.": "dit/xattn"}
     for prefix, scope in scopes.items():
         ops_ = [op for k, op in kernels if k.startswith(prefix)]
         assert len(ops_) == layers, prefix
         assert all(scope_path(op).endswith(scope) for op in ops_)
     assert _names(kernels) == {"%_dit_flash", "%_dit_xflash"}
+
+
+@pytest.mark.parametrize("arch", ["dit-xl", "pixart-alpha"])
+def test_seq_step_copies_no_block_weight(one_chip, arch, monkeypatch):
+    """At full widths and 1024 tokens, the served seq step has no fusion,
+    copy or transpose whose output has the shape of a block weight: the
+    dots read each layer's f32 weights as they are.  (A layer loop that
+    slices stacked blocks writes 224 such outputs a DiT step, 2.08 GB,
+    and 476 a PixArt step, 1.19 GB: bf16 copies for the MXU.)"""
+    from repro.configs.registry import ARCHS
+    from repro.kernels import ops
+    from repro.launch import serve
+    from repro.models.pdefs import is_def
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(ARCHS[arch], num_tokens=1024)
+    text = _step_program(one_chip, cfg, "seq")
+    blocks = serve.denoiser(cfg).defs(cfg)["blocks"]
+    shapes = {",".join(map(str, d.shape[1:]))
+              for d in jax.tree.leaves(blocks, is_leaf=is_def)
+              if len(d.shape) > 2}
+    outputs = re.findall(r"= (.*?) (?:fusion|copy|transpose)\(", text)
+    assert len(outputs) > 500
+    copies = [sh for out in outputs
+              for sh in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", out)
+              if sh in shapes]
+    assert copies == []
